@@ -11,81 +11,35 @@ import os as _os
 
 import jax as _jax
 
-# Compatibility shims: the codebase targets the modern jax API surface
-# (jax.shard_map, jax.lax.axis_size, jax.enable_x64); on older jax (< 0.6,
-# e.g. the baked 0.4.x toolchain) those live elsewhere or need flags.
-# Alias them so the distributed tier works on both.
-if not hasattr(_jax, "shard_map"):  # pragma: no cover - version dependent
-    try:
-        from functools import wraps as _wraps
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @_wraps(_shard_map)
-        def _shard_map_compat(f, *args, **kwargs):
-            # 0.4.x shard_map lacks replication rules for while/scan; the
-            # modern entry point tolerates them, so default the check off
-            # (this is jax's own documented workaround).
-            kwargs.setdefault("check_rep", False)
-            return _shard_map(f, *args, **kwargs)
-
-        _jax.shard_map = _shard_map_compat
-    except Exception:
-        pass
-
-if not hasattr(_jax.lax, "axis_size"):  # pragma: no cover - version dependent
-    def _axis_size(axis_name):
-        frame = _jax.core.axis_frame(axis_name)
-        # 0.4.x axis_frame returns the size itself; later returns a frame.
-        return getattr(frame, "size", frame)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax, "enable_x64"):  # pragma: no cover - version dependent
-    try:
-        from jax.experimental import enable_x64 as _enable_x64
-
-        _jax.enable_x64 = _enable_x64
-    except Exception:
-        pass
+#: Root of the checkout this package lives in.
+CHECKOUT_DIR = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 
 # Persistent XLA compilation cache: multilevel runs hit a bounded set of
-# power-of-2 kernel shapes (see graph/csr.py PaddedView); caching them on disk
-# makes every run after the first start hot (measured 6.4x on a full CPU
-# partition, round 4).  Enabled on every backend; the round-3 CPU
-# serializer crashes traced to AOT executable caching, which stays off via
-# jax_persistent_cache_enable_xla_caches="none" below.  Override dir or
-# disable via env.
-if _os.environ.get("KAMINPAR_TPU_NO_CACHE", "0") != "1":
-    _cache_dir = _os.environ.get(
-        "KAMINPAR_TPU_CACHE_DIR",
-        _os.path.join(_os.path.expanduser("~"), ".cache", "kaminpar_tpu", "xla"),
+# shape-ladder kernel shapes (graph/csr.py PaddedView), so caching them on
+# disk makes every run after the first start warm.  The cache directory is a
+# deployment setting with one owner: JAX_COMPILATION_CACHE_DIR when it is
+# set (jax reads it itself), else <checkout>/.xla_cache.  Nothing else in the
+# package sets a cache directory.  AOT executable caching stays off: jaxlib's
+# CPU executable serializer crashed intermittently while writing entries, and
+# cross-machine AOT artifacts reload with machine-feature mismatches.
+_jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+_jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir", _os.path.join(CHECKOUT_DIR, ".xla_cache")
     )
-    try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        # The AOT-executable guard must be configured BEFORE the cache dir
-        # goes live: jaxlib's executable serializer intermittently
-        # SIGSEGV/SIGABRTs inside put_executable_and_time on the CPU
-        # backend (observed crashing the test suite from two different
-        # kernels), and cross-machine AOT artifacts reload with
-        # machine-feature mismatches.  Caching the HLO/compilation only
-        # keeps most of the warm-start benefit; if this option is missing
-        # (older jax), the except below leaves the cache fully disabled.
-        _jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    except Exception:  # pragma: no cover — cache is an optimization only
-        try:
-            _jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
 
-from .context import Context, PartitioningMode
-from .presets import create_context_by_preset_name, create_default_context
+from .context import Context, PartitioningMode  # noqa: E402
+from .kaminpar import KaMinPar  # noqa: E402
+from .presets import (  # noqa: E402
+    create_context_by_preset_name,
+    create_default_context,
+)
 
 __all__ = [
     "Context",
+    "KaMinPar",
     "PartitioningMode",
     "create_context_by_preset_name",
     "create_default_context",

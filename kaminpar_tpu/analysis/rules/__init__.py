@@ -17,7 +17,7 @@ rng-discipline      randomness flows from utils/rng (lane keys or the
 donation-safety     buffers donated via donate_argnums are not referenced
                     after the jitted call
 error-discipline    pipeline/serve failures route through the round-17
-                    resilience taxonomy (no bare RuntimeError, dispatch-
+                    resilience hierarchy (no bare RuntimeError, dispatch-
                     site handlers call resilience.errors.classify)
 ==================  =======================================================
 """

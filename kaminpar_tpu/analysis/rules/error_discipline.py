@@ -1,7 +1,7 @@
-"""error-discipline: failures route through the resilience taxonomy.
+"""error-discipline: failures route through the resilience hierarchy.
 
 Round 17 (ISSUE 13): every pipeline/serve failure is classified into the
-typed taxonomy of :mod:`kaminpar_tpu.resilience.errors` by the ONE
+typed hierarchy of :mod:`kaminpar_tpu.resilience.errors` by the ONE
 classifier, so breakers, the degradation ladder, and operators share a
 vocabulary.  This rule keeps the discipline from eroding:
 
@@ -73,7 +73,7 @@ def _catches_broad(handler: ast.ExceptHandler) -> bool:
 class ErrorDisciplineRule(Rule):
     name = "error-discipline"
     description = (
-        "pipeline/serve failures route through the resilience taxonomy: "
+        "pipeline/serve failures route through the resilience hierarchy: "
         "no bare RuntimeError raises, no laundering caught failures into "
         "untyped errors, dispatch-site except handlers must call "
         "resilience.errors.classify"

@@ -9,12 +9,13 @@ an explicit per-graph pin (``g._layout_mode = ...``,
 ``partitioning/deep.py:_nested_partition``).  This rule makes the whole
 contract static over the device-disciplined tier:
 
-1. no calls to the process-default mutators (``configure_compilation_cache``
-   / ``configure_layout_build`` / ``configure_sync_timers`` /
+1. no calls to the process-default mutators (``configure_layout_build``
+   / ``configure_sync_timers`` /
    ``set_layout_build_mode`` / ``timer.set_sync_mode``) — those belong to
    offline entry points (tools, bench), never to pipeline code;
-2. no direct ``jax.config.update("jax_compilation_cache...")`` — cache
-   ownership goes through ``EngineRuntime.activate``;
+2. no direct ``jax.config.update("jax_compilation_cache...")`` — the
+   cache directory is a deployment setting fixed once at import
+   (``kaminpar_tpu/__init__.py``);
 3. no reads of the module-level defaults (``_layout_build_mode``) — resolve
    through ``resolve_layout_build_mode`` / ``current_runtime()``;
 4. every locally constructed ``CSRGraph`` / ``from_numpy_csr`` graph must
@@ -32,14 +33,12 @@ from ..core import Finding, LintConfig, Rule, SourceModule
 from ._walk import iter_scopes, walk_scope
 
 _BANNED_CALL_SUFFIXES = (
-    "context.configure_compilation_cache",
     "context.configure_layout_build",
     "context.configure_sync_timers",
     "csr.set_layout_build_mode",
     "timer.set_sync_mode",
 )
 _BANNED_CALL_NAMES = (
-    "configure_compilation_cache",
     "configure_layout_build",
     "configure_sync_timers",
     "set_layout_build_mode",
@@ -74,8 +73,8 @@ def _target_path(node: ast.AST):
 class RuntimeIsolationRule(Rule):
     name = "runtime-isolation"
     description = (
-        "pipeline code must reach compilation-cache/layout/sync settings "
-        "through the active EngineRuntime, never the process defaults"
+        "pipeline code must reach layout/sync settings through the "
+        "active EngineRuntime, and never reconfigure the process"
     )
 
     def check(self, mod: SourceModule, config: LintConfig) -> List[Finding]:
@@ -115,8 +114,9 @@ class RuntimeIsolationRule(Rule):
                 ):
                     out.append(self.finding(
                         mod, node,
-                        "direct compilation-cache config mutation — cache "
-                        "ownership goes through EngineRuntime.activate()",
+                        "direct compilation-cache config mutation — the "
+                        "cache directory is fixed once at import "
+                        "(kaminpar_tpu/__init__.py)",
                     ))
             elif isinstance(node, (ast.Name, ast.Attribute)):
                 name = node.id if isinstance(node, ast.Name) else node.attr

@@ -29,11 +29,12 @@ def _intersect_clusterings(la, lb):
     (the LP convention): each (la, lb) run is relabeled to its smallest
     member."""
     n = la.shape[0]
-    order = jnp.lexsort((lb, la))
-    from ..ops.segment import run_starts2
+    from ..ops.segment import cumsum, run_starts2
+    from ..ops.sort import argsort
 
+    order = argsort((la, lb))
     first = run_starts2(la[order], lb[order])
-    rid = jnp.cumsum(first.astype(jnp.int32)) - 1
+    rid = cumsum(first.astype(jnp.int32)) - 1
     rep = jax.ops.segment_min(order.astype(la.dtype), rid, num_segments=n)
     return jnp.zeros_like(la).at[order].set(rep[rid])
 
@@ -72,55 +73,21 @@ class LPClustering:
                 "kernels; falling back to uniform tie-breaking"
             )
 
-    def _iterate_fn(self):
-        """LP sweep-loop implementation per the lp_kernel backend switch
-        (ops/pallas_lp.py: fused Pallas kernels, bit-identical off-TPU via
-        interpret mode)."""
-        from ..ops.pallas_lp import select_lp_ops
-
-        # probe=True: _run_iterate guards the dispatch and reports the
-        # outcome back, so this call site may consume the lp_pallas
-        # breaker's half-open probe slot (the refiners may not).
-        return select_lp_ops(self.ctx.lp_kernel, probe=True)[0]
-
     def _run_iterate(self, iterate, xla_iterate, *args, **kwargs):
-        """Dispatch one LP sweep loop with the round-17 pallas->xla
-        degradation rung: a failing Pallas dispatch is classified,
-        recorded on the ``lp_pallas`` breaker (opening it demotes every
-        later ``select_lp_ops`` selection until the half-open probe
-        recovers), and retried in-flight on the XLA twin — which is
-        bit-identical by construction, so the demotion never changes
-        results.  A successful Pallas dispatch reports the breaker
-        success (closing a half-open probe restores the primary path)."""
+        """Dispatch one LP sweep loop.  A failing fused-Pallas dispatch is
+        raised as its classified error (resilience/errors.py): there is no
+        silent rerun on the XLA twin, so a run that returns used the kernel
+        it was configured with."""
         if iterate is xla_iterate:
             return xla_iterate(*args, **kwargs)
-        from ..resilience.breakers import global_registry
         from ..resilience.errors import classify
         from ..resilience.faults import maybe_inject
 
-        reg = global_registry()
-        breaker = reg.get("lp_pallas")
-        # The iterate twins donate their state carry (args[0]): a pallas
-        # failure AFTER dispatch has already consumed the buffer, so the
-        # retry must run from a pre-attempt copy — re-passing the donated
-        # state would raise "Array has been deleted" and kill the exact
-        # recovery this rung exists for.  The copy is O(n_pad) LP state
-        # (labels + label weights), tiny next to the adjacency.
-        state_backup = jax.tree_util.tree_map(
-            lambda x: x.copy() if isinstance(x, jax.Array) else x, args[0]
-        )
         try:
             maybe_inject("execute", site="lp_pallas")
-            state = iterate(*args, **kwargs)
-        except Exception as exc:  # noqa: BLE001 — the XLA twin is the
-            # bit-identical fallback for every pallas failure class
-            err = classify(exc, site="lp_pallas")
-            breaker.record_failure()
-            reg.record_demotion("lp_pallas", err.failure_class)
-            return xla_iterate(state_backup, *args[1:], **kwargs)
-        if breaker.record_success():
-            reg.record_restoration("lp_pallas")
-        return state
+            return iterate(*args, **kwargs)
+        except Exception as exc:
+            raise classify(exc, site="lp_pallas") from exc
 
     def compute_clustering(self, graph, max_cluster_weight: int):
         """Returns padded labels (over graph.padded(), or the equal-shape
@@ -185,7 +152,9 @@ class LPClustering:
         ):
             # see LabelPropagationContext.low_degree_boost_threshold
             iters *= max(self.ctx.low_degree_boost_factor, 1)
-        iterate = self._iterate_fn()
+        from ..ops.pallas_lp import select_lp_ops
+
+        iterate = select_lp_ops(self.ctx.lp_kernel)[0]
         state = self._run_iterate(
             iterate,
             lp.lp_iterate_bucketed,
@@ -255,7 +224,7 @@ class LPClustering:
             iters *= max(self.ctx.low_degree_boost_factor, 1)
         from ..ops.pallas_lp import select_compressed_iterate
 
-        iterate = select_compressed_iterate(self.ctx.lp_kernel, probe=True)
+        iterate = select_compressed_iterate(self.ctx.lp_kernel)
         state = self._run_iterate(
             iterate,
             lp.lp_iterate_compressed,

@@ -12,14 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-
-try:  # tomllib is stdlib from Python 3.11; fall back to tomli on 3.10
-    import tomllib as _toml
-except ModuleNotFoundError:  # pragma: no cover - version dependent
-    try:
-        import tomli as _toml
-    except ModuleNotFoundError:
-        _toml = None
+import tomllib as _toml
 
 from .context import Context, RefinementAlgorithm
 
@@ -93,11 +86,6 @@ def load_toml(text: str, base: Context | None = None) -> Context:
     """Parse a TOML config over a base context (default preset if None)."""
     from .presets import create_context_by_preset_name
 
-    if _toml is None:
-        raise RuntimeError(
-            "TOML config loading needs Python >= 3.11 (tomllib) or the "
-            "tomli package"
-        )
     d = _toml.loads(text)
     preset = d.pop("preset_name", None)
     if base is None:

@@ -67,6 +67,7 @@ class BucketedView(NamedTuple):
         return r + int(self.heavy.nodes.shape[0])
 
 
+from ..ops.segment import cumsum, nonzero, take
 from ..utils.intmath import next_pow2 as _next_pow2
 
 # Width classes of the degree histogram that rides the contraction level's
@@ -297,16 +298,16 @@ def _device_bucket(row_ptr, col, ew, gather_idx, n, merged_to, base, R, *,
     real = jnp.arange(n_pad) < n
     cls, heavy = degree_classes(deg, real)
     mask = real & ~heavy & (merged_to[cls.astype(jnp.int32)] == w)
-    nodes = jnp.nonzero(mask, size=R_pad, fill_value=anchor)[0].astype(idt)
+    nodes = nonzero(mask, size=R_pad, fill_value=anchor).astype(idt)
     rows_ok = jnp.arange(R_pad) < R
     degn = jnp.where(rows_ok, deg[nodes], 0)
     slot = jnp.arange(w)
     idx = row_ptr[nodes][:, None] + slot[None, :]
     valid = slot[None, :] < degn[:, None]
     safe = jnp.minimum(idx, m_pad - 1)
-    cols_b = jnp.where(valid, col[safe], nodes[:, None])
-    wgts_b = jnp.where(valid, ew[safe], 0)
-    rank = (jnp.cumsum(mask) - 1).astype(idt)
+    cols_b = jnp.where(valid, take(col, safe), nodes[:, None])
+    wgts_b = jnp.where(valid, take(ew, safe), 0)
+    rank = (cumsum(mask) - 1).astype(idt)
     gi = jnp.where(mask, base.astype(idt) + rank, gather_idx)
     return nodes, cols_b, wgts_b, gi
 
@@ -321,12 +322,12 @@ def _device_heavy(row_ptr, col, ew, edge_u, gather_idx, n, base, Hs, *,
     deg = row_ptr[1:] - row_ptr[:-1]
     real = jnp.arange(n_pad) < n
     _, heavy = degree_classes(deg, real)
-    hnodes = jnp.nonzero(heavy, size=Hr_pad, fill_value=anchor)[0].astype(idt)
-    hrank = (jnp.cumsum(heavy) - 1).astype(idt)
+    hnodes = nonzero(heavy, size=Hr_pad, fill_value=anchor).astype(idt)
+    hrank = (cumsum(heavy) - 1).astype(idt)
     # Heavy CSR slots ascending == host's per-node slot enumeration (pad
     # edges belong to the anchor, which real-mask excludes from heavy).
     edge_sel = heavy[edge_u]
-    hslots = jnp.nonzero(edge_sel, size=Hs_pad, fill_value=0)[0]
+    hslots = nonzero(edge_sel, size=Hs_pad, fill_value=0)
     slot_ok = jnp.arange(Hs_pad) < Hs
     safe = jnp.minimum(hslots, m_pad - 1)
     hcols = jnp.where(slot_ok, col[safe], anchor).astype(idt)

@@ -329,6 +329,16 @@ def resolve_ip_backend(ctx: Optional[InitialPartitioningContext]) -> str:
     return mode
 
 
+def device_pool_supports(g: HostCSR, max_w: np.ndarray) -> bool:
+    """Whether the int32 device pool can carry this bisection's weights
+    (node/edge weight totals and budgets below 2^31); wider inputs take
+    the host pool."""
+    return max(
+        g.total_node_weight, int(max_w[0]), int(max_w[1]),
+        int(np.asarray(g.edge_w, dtype=np.int64).sum()),
+    ) < 2**31
+
+
 def _device_bipartition(
     g: HostCSR, max_w: np.ndarray, rng, ctx: InitialPartitioningContext,
     final_k: int,
@@ -342,10 +352,8 @@ def _device_bipartition(
     from ..ops.bipartition import pool_bipartition_device
     from ..resilience.faults import maybe_inject
 
-    # Injection BEFORE the seed draw: a faulted bisection then leaves the
-    # host stream exactly where a pure-host run would have it, so the
-    # ip_device -> ip_host demotion is bit-identical to running with
-    # ip_backend="host" from the start (the chaos matrix asserts this).
+    # Named injection point: the chaos matrix asserts that a faulted
+    # bisection surfaces as a typed error.
     maybe_inject("execute", site="ip_device")
     seed = int(rng.integers(1 << 62))
     labels, _ = pool_bipartition_device(
@@ -373,51 +381,19 @@ def multilevel_bipartition(
     """
     ctx = ctx or InitialPartitioningContext()
     if g.n > 2 and resolve_ip_backend(ctx) == "device":
-        from ..resilience.breakers import global_registry
+        if device_pool_supports(g, max_w):
+            # A device-pool failure surfaces as its classified error: there
+            # is no rescue through the host pool, so a run that returns
+            # used the backend it resolved.
+            from ..resilience.errors import classify
 
-        breaker = global_registry().get("ip_device")
-        if not breaker.allow():
-            # Breaker open (round 17): the device pool failed its way past
-            # the threshold — serve this bisection from the host pool
-            # without paying a doomed dispatch; the half-open probe after
-            # the cooldown re-admits the device path.
-            global_registry().record_demotion(
-                "ip_device", "circuit breaker open"
-            )
-            from ..ops.bipartition import count_pool_fallback
-
-            count_pool_fallback()
-        else:
             try:
-                labels = _device_bipartition(g, max_w, rng, ctx, final_k)
-                if breaker.record_success():
-                    global_registry().record_restoration("ip_device")
-                return labels
-            except Exception as exc:  # noqa: BLE001 — host pool is the fallback
-                import warnings
+                return _device_bipartition(g, max_w, rng, ctx, final_k)
+            except Exception as exc:
+                raise classify(exc, site="ip_device") from exc
+        from ..ops.bipartition import count_pool_fallback
 
-                from ..ops.bipartition import count_pool_fallback
-                from ..resilience.errors import classify
-
-                # Loud + counted: a systematic kernel regression would
-                # otherwise silently serve every bisection from the host
-                # pool while bench reports ip_backend="device" (the counter
-                # rides its ip_pool census as "fallbacks").  The failure is
-                # classified into the round-17 taxonomy and recorded on the
-                # ip_device breaker so repeats open it instead of taxing
-                # every bisection with a doomed dispatch.
-                err = classify(exc, site="ip_device")
-                breaker.record_failure()
-                global_registry().record_demotion(
-                    "ip_device", err.failure_class, warn=False
-                )
-                count_pool_fallback()
-                warnings.warn(
-                    f"device IP pool failed ({err.failure_class}: {exc}); "
-                    "falling back to the host pool for this bisection",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
+        count_pool_fallback()  # too wide for the device pool: host pool
     C = ctx.coarsening_contraction_limit
     total = g.total_node_weight
 

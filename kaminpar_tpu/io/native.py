@@ -2,8 +2,9 @@
 
 Reference: the reference's IO is C++ (kaminpar-io/metis_parser.cc mmap
 tokenizer); this is the TPU build's native equivalent.  The shared library
-is built lazily with g++ into a content-hashed cache directory and loaded
-via ctypes — no pybind11/Python-C-API dependency.  Every entry degrades to
+is built lazily with g++ next to its source (``_native/metis_native.so``,
+rebuilt when the source is newer) and loaded via ctypes — no
+pybind11/Python-C-API dependency.  Every entry degrades to
 the pure-NumPy parser when the toolchain or build is unavailable
 (KAMINPAR_TPU_NO_NATIVE=1 forces the fallback).
 """
@@ -11,7 +12,6 @@ the pure-NumPy parser when the toolchain or build is unavailable
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import os
 import subprocess
 
@@ -19,6 +19,7 @@ import numpy as np
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_native",
                     "metis_native.cpp")
+_SO = os.path.splitext(_SRC)[0] + ".so"
 _lib = None
 _lib_failed = False
 
@@ -35,13 +36,6 @@ class _KpMetisGraph(ctypes.Structure):
     ]
 
 
-def _cache_dir() -> str:
-    base = os.environ.get("KAMINPAR_TPU_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "kaminpar_tpu"
-    )
-    return os.path.join(base, "native")
-
-
 def _load():
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
@@ -50,19 +44,18 @@ def _load():
         _lib_failed = True
         return None
     try:
-        with open(_SRC, "rb") as fh:
-            digest = hashlib.sha256(fh.read()).hexdigest()[:16]
-        so_path = os.path.join(_cache_dir(), f"metis_native_{digest}.so")
-        if not os.path.exists(so_path):
-            os.makedirs(os.path.dirname(so_path), exist_ok=True)
-            tmp = so_path + f".tmp{os.getpid()}"
+        if (
+            not os.path.exists(_SO)
+            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)
+        ):
+            tmp = _SO + f".tmp{os.getpid()}"
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC,
                  "-o", tmp],
                 check=True, capture_output=True, timeout=120,
             )
-            os.replace(tmp, so_path)  # atomic vs concurrent builders
-        lib = ctypes.CDLL(so_path)
+            os.replace(tmp, _SO)  # atomic against a concurrent build
+        lib = ctypes.CDLL(_SO)
         lib.kp_parse_metis.argtypes = [ctypes.c_char_p,
                                        ctypes.POINTER(_KpMetisGraph)]
         lib.kp_parse_metis.restype = ctypes.c_int

@@ -50,6 +50,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .segment import cumsum
+from .sort import argsort, rank_below
+
 # Packed-stats layout appended to the winning labels (all in the graph's
 # index dtype): [cut, feasible, winner_lane, num_feasible_lanes, w0, w1].
 STATS_LEN = 6
@@ -140,13 +143,24 @@ def method_lane_keys(seed: int, methods: Tuple[Tuple[str, int], ...]):
 # ---------------------------------------------------------------------------
 
 
+def _row_sums(vals, edge_u, n_pad: int):
+    """Per-node sums of the edge values ``vals`` over the CSR rows, as
+    differences of one prefix sum at the row bounds.  Equals
+    ``segment_sum(vals, edge_u, n_pad)`` exactly (integers; ``edge_u`` is
+    ascending), without the scatter-add, which a TPU runs slowly when it
+    is repeated in every lane's grow and FM loops."""
+    bounds = jnp.searchsorted(
+        edge_u, jnp.arange(n_pad + 1, dtype=edge_u.dtype), side="left"
+    )
+    cs = jnp.concatenate([jnp.zeros(1, vals.dtype), cumsum(vals)])
+    return cs[bounds[1:]] - cs[bounds[:-1]]
+
+
 def _connections(in0, edge_u, col_idx, edge_w, n_pad: int):
     """Per-node edge weight into block 0 and into block 1.  Pad edges have
     weight 0, so padding contributes to neither."""
-    to0 = jax.ops.segment_sum(
-        jnp.where(in0[col_idx], edge_w, 0), edge_u, num_segments=n_pad
-    )
-    degw = jax.ops.segment_sum(edge_w, edge_u, num_segments=n_pad)
+    to0 = _row_sums(jnp.where(in0[col_idx], edge_w, 0), edge_u, n_pad)
+    degw = _row_sums(edge_w, edge_u, n_pad)
     return to0, degw - to0
 
 
@@ -162,13 +176,14 @@ def _admit_prefix(sort_keys, cand, node_w, budget):
     block every lighter node behind it (the host pool's queues *skip*
     unmovable nodes and continue; this is the prefix-form equivalent)."""
     cand = cand & (node_w <= budget)
-    order = jnp.lexsort(sort_keys)
+    keys = sort_keys[::-1]
+    order = argsort(keys)
     cand_s = cand[order]
     w_s = jnp.where(cand_s, node_w[order], 0)
-    cum = jnp.cumsum(w_s)
-    ok_s = cand_s & (cum <= budget)
-    admit = jnp.zeros_like(cand).at[order].set(ok_s)
-    return admit, jnp.sum(jnp.where(ok_s, w_s, 0))
+    cum = cumsum(w_s)
+    fits = cum <= budget  # a prefix of the sorted order (cum is monotone)
+    admit = cand & rank_below(keys, order, jnp.sum(fits))
+    return admit, jnp.sum(jnp.where(cand_s & fits, w_s, 0))
 
 
 def _rand_prio(key):
@@ -206,20 +221,23 @@ def _rebalance_side(key, in0, edge_u, col_idx, edge_w, node_w, max_w0, max_w1,
     # behind it (see _admit_prefix — without this, one unmovable heavy
     # node leaves a trivially repairable lane infeasible).
     cand = cand & (node_w <= room)
-    order = jnp.lexsort((prio, -gain))
+    keys = (-gain, prio)
+    order = argsort(keys)
     cand_s = cand[order]
     w_s = jnp.where(cand_s, node_w[order], 0)
-    cum = jnp.cumsum(w_s)
+    cum = cumsum(w_s)
     # Minimal covering prefix: admit while the weight moved *before* this
-    # node is still short of the overload, and the receiver keeps fitting.
-    move_s = cand_s & (cum - w_s < over) & (cum <= room)
-    move = jnp.zeros_like(in0).at[order].set(move_s)
+    # node is still short of the overload, and the receiver keeps fitting
+    # (both conditions hold on a prefix of the sorted order).
+    prefix = (cum - w_s < over) & (cum <= room)
+    move = cand & rank_below(keys, order, jnp.sum(prefix))
     return (in0 & ~move) if side == 0 else (in0 | move)
 
 
-def _fm_round(key, in0, edge_u, col_idx, edge_w, node_w, max_w0, max_w1, side0):
+def _fm_round(key, in0, conn0, conn1, node_w, max_w0, max_w1, side0):
     """One boundary-LP/FM round from a single (traced) source side: move the
     best positive-gain prefix that fits the receiving side's budget.
+    ``conn0``/``conn1`` are :func:`_connections` of ``in0``.
 
     Zero-gain moves are admitted with a per-node coin flip (the reference
     initial FM escapes plateaus through its rollback hill-climbing;
@@ -231,7 +249,6 @@ def _fm_round(key, in0, edge_u, col_idx, edge_w, node_w, max_w0, max_w1, side0):
     the best-state tracker in the lane loop banks the best visit."""
     n_pad = node_w.shape[0]
     kp, kc = jax.random.split(key)
-    conn0, conn1 = _connections(in0, edge_u, col_idx, edge_w, n_pad)
     total = jnp.sum(node_w)
     w0 = jnp.sum(jnp.where(in0, node_w, 0))
     w1 = total - w0
@@ -268,7 +285,7 @@ def _lane_bipartition(key, edge_u, col_idx, edge_w, node_w, n, target,
 
         def grow(t, carry):
             in0, w0 = carry
-            conn0, _ = _connections(in0, edge_u, col_idx, edge_w, n_pad)
+            conn0 = _row_sums(jnp.where(in0[col_idx], edge_w, 0), edge_u, n_pad)
             cand = (~in0) & (conn0 > 0)  # frontier: adjacent to block 0
             prio = _rand_prio(jax.random.fold_in(k_grow, t))((n_pad,))
             # BFS admits the layer in random order; GGG orders it by
@@ -286,34 +303,45 @@ def _lane_bipartition(key, edge_u, col_idx, edge_w, node_w, n, target,
             node_w, max_w0, max_w1, side=side,
         )
 
-    def score(mask):
+    # Per-node weighted degree, shared by every connection count below.
+    degw = _row_sums(edge_w, edge_u, n_pad)
+
+    def connections(mask):
+        to0 = _row_sums(jnp.where(mask[col_idx], edge_w, 0), edge_u, n_pad)
+        return to0, degw - to0
+
+    def score(mask, conn0, conn1):
         """(overload, cut): lexicographically smaller is better; overload 0
-        == feasible, so overload-first subsumes feasibility-first."""
+        == feasible, so overload-first subsumes feasibility-first.  The cut
+        counts each cut edge from both ends, from the connections."""
         w0 = jnp.sum(jnp.where(mask, node_w, 0))
         w1 = jnp.sum(node_w) - w0
         over = jnp.maximum(w0 - max_w0, 0) + jnp.maximum(w1 - max_w1, 0)
-        cut = jnp.sum(jnp.where(mask[edge_u] != mask[col_idx], edge_w, 0))
+        cut = jnp.sum(jnp.where(mask, conn1, conn0))
         return over, cut
 
     def fm(t, carry):
-        in0, best, b_over, b_cut = carry
+        # The connections of in0 ride the carry: one count per round.
+        in0, conn0, conn1, best, b_over, b_cut = carry
         in0 = _fm_round(
-            jax.random.fold_in(k_fm, t), in0, edge_u, col_idx, edge_w,
+            jax.random.fold_in(k_fm, t), in0, conn0, conn1,
             node_w, max_w0, max_w1, (t % 2) == 0,
         )
-        over, cut = score(in0)
+        conn0, conn1 = connections(in0)
+        over, cut = score(in0, conn0, conn1)
         better = (over < b_over) | ((over == b_over) & (cut < b_cut))
         return (
-            in0,
+            in0, conn0, conn1,
             jnp.where(better, in0, best),
             jnp.where(better, over, b_over),
             jnp.where(better, cut, b_cut),
         )
 
-    over0, cut0 = score(in0)
-    _, best, _, _ = jax.lax.fori_loop(
-        0, fm_rounds, fm, (in0, in0, over0, cut0)
-    )
+    conn0, conn1 = connections(in0)
+    over0, cut0 = score(in0, conn0, conn1)
+    best = jax.lax.fori_loop(
+        0, fm_rounds, fm, (in0, conn0, conn1, in0, over0, cut0)
+    )[3]
     return best
 
 
@@ -397,9 +425,9 @@ _pool_stats: Dict[str, float] = {
 
 
 def count_pool_fallback() -> None:
-    """Record one device-pool bisection that fell back to the host pool —
-    a systematic kernel regression must not hide behind the silent
-    fallback (the census rides bench.py's ``ip_pool`` record)."""
+    """Count one bisection that resolved to the device pool but ran on the
+    host pool (weights too wide for int32, see
+    ``initial.bipartitioner.device_pool_supports``)."""
     with _stats_lock:
         _pool_stats["fallbacks"] += 1
 
@@ -443,7 +471,8 @@ def pool_bipartition_device(
     vmapped lane, and performs the bisection's single blocking readback —
     the packed winning labels + stats.  Returns ``(labels[:n] int32, stats
     dict)``.  Raises on inputs the int32 kernel cannot carry (weights at or
-    beyond 2^31) so callers can fall back to the host pool.
+    beyond 2^31); ``initial.bipartitioner.device_pool_supports`` routes
+    those to the host pool before dispatch.
     """
     from ..graph.csr import from_numpy_csr
     from ..utils import sync_stats

@@ -18,7 +18,16 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.bucketed import Bucket, HeavyPart
-from .segment import run_starts2
+from .segment import (
+    cummax,
+    cumsum,
+    row_cummax,
+    row_cumsum,
+    run_starts2,
+    take,
+    take_rows,
+)
+from .sort import argsort
 
 _I32MAX = 2**31 - 1
 
@@ -27,7 +36,9 @@ def lookup(table_or_scalar, idx):
     """Index a per-label table, or broadcast a scalar limit (saves a large
     random gather when the limit is uniform, as in clustering)."""
     t = jnp.asarray(table_or_scalar)
-    return t if t.ndim == 0 else t[idx]
+    if t.ndim == 0:
+        return t
+    return take(t, idx) if jnp.ndim(idx) == 2 else t[idx]
 
 
 def _bucket_moves(
@@ -49,12 +60,12 @@ def _bucket_moves(
     own = labels[nodes]  # (R,)
     nw = node_w[nodes]  # (R,)
 
-    L = labels[cols]  # (R, w) neighbor labels
+    L = take(labels, cols)  # (R, w) neighbor labels
     W = wgts
     own_conn = jnp.sum(jnp.where(L == own[:, None], W, 0), axis=1)
 
     Ls, Ws = jax.lax.sort((L, W), dimension=1, num_keys=1)
-    c = jnp.cumsum(Ws, axis=1)
+    c = row_cumsum(Ws)
     change = Ls[:, 1:] != Ls[:, :-1]
     start = jnp.concatenate([jnp.ones((R, 1), bool), change], axis=1)
     end = jnp.concatenate([change, jnp.ones((R, 1), bool)], axis=1)
@@ -62,7 +73,7 @@ def _bucket_moves(
     # cumsum value just before the run began (propagated by a row cummax, which
     # is monotone because weights are non-negative).
     base = jnp.where(start, c - Ws, 0)
-    run_base = jax.lax.cummax(base, axis=1)
+    run_base = row_cummax(base)
     rating = c - run_base
 
     is_cur = Ls == own[:, None]
@@ -72,7 +83,9 @@ def _bucket_moves(
     if external_only:
         ok = ok & ~is_cur
     if respect_caps:
-        fits = label_weights[Ls] + nw[:, None] <= lookup(max_label_weights, Ls)
+        fits = take(label_weights, Ls) + nw[:, None] <= lookup(
+            max_label_weights, Ls
+        )
         ok = ok & fits if external_only else ok & (is_cur | fits)
 
     score = jnp.where(ok, rating, -1)
@@ -88,7 +101,7 @@ def _bucket_moves(
     tie = jax.random.randint(key, (R, w), 0, _I32MAX, dtype=jnp.int32)
     tie_m = jnp.where(eligible, tie, -1)
     slot = jnp.argmax(tie_m, axis=1)
-    target = jnp.where(has, jnp.take_along_axis(Ls, slot[:, None], axis=1)[:, 0], own)
+    target = jnp.where(has, take_rows(Ls, slot), own)
     tconn = jnp.where(has, best, 0)
     return target, tconn, own_conn, has
 
@@ -107,6 +120,7 @@ def flat_best_moves(
     external_only: bool,
     respect_caps: bool,
     tie_break: str = "uniform",
+    fast_compile: bool = False,
 ):
     """Flat run-reduce best-move kernel over (row, candidate-label, weight)
     slot triples: one variadic sort by (row, label), then run ratings via the
@@ -115,13 +129,23 @@ def flat_best_moves(
 
     Shared by the heavy path of the bucketed layout and the per-shard
     distributed LP kernel (dist/lp.py).  ``own``/``node_w_row`` are
-    (num_rows,); returns per-row (target, tconn, own_conn, has_cand)."""
+    (num_rows,); returns per-row (target, tconn, own_conn, has_cand).
+
+    ``fast_compile`` sorts and scans with the forms of ops/sort.py and
+    ops/segment.py, whose TPU compile time does not grow with ``S``; the
+    result is the same.  The sharded path keeps XLA's own sort and scans
+    until those forms have run on a real mesh."""
     S = cand.shape[0]
-    sr, sc, sw = jax.lax.sort((row, cand, w), dimension=0, num_keys=2)
+    if fast_compile:
+        order = argsort((row, cand))
+        sr, sc, sw = row[order], cand[order], w[order]
+        c = cumsum(sw)
+    else:
+        sr, sc, sw = jax.lax.sort((row, cand, w), dimension=0, num_keys=2)
+        c = jnp.cumsum(sw)
     first = run_starts2(sr, sc)
-    c = jnp.cumsum(sw)
     base = jnp.where(first, c - sw, 0)
-    run_base = jax.lax.cummax(base)
+    run_base = cummax(base) if fast_compile else jax.lax.cummax(base)
     rating = c - run_base  # valid at run *ends*
     # mark run ends so per-row maxima only consider complete run totals
     end = jnp.concatenate([first[1:], jnp.ones(1, dtype=bool)]) if S else first
@@ -188,7 +212,7 @@ def _heavy_moves(
         key, hrow, labels[hcols], hw, labels[hnodes], node_w[hnodes],
         label_weights, max_label_weights, num_rows=hnodes.shape[0],
         external_only=external_only, respect_caps=respect_caps,
-        tie_break=tie_break,
+        tie_break=tie_break, fast_compile=True,
     )
 
 

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from ..graph.bucketed import WIDTH_CLASSES, device_deg_histogram
 from ..graph.csr import CSRGraph, PaddedView, _next_bucket
 from ..utils import sync_stats
-from .segment import run_ids, run_starts2
+from .segment import compact, cumsum, run_ids, run_starts2
+from .sort import argsort
 
 # stats layout: [n_c_full, m_c, max_node_w, total_edge_w, hist*10, Hr, Hs]
 STATS_LEN = 4 + len(WIDTH_CLASSES) + 2
@@ -40,21 +41,16 @@ STATS_LEN = 4 + len(WIDTH_CLASSES) + 2
 def _edge_sort_perm(ku, kv, sentinel: int):
     """Permutation sorting edges by (ku, kv) with original order on ties.
 
-    Single fused-key ``lax.sort`` when the composite key fits the widest
-    enabled integer dtype (one sort pass carrying 2 operands with a scalar
-    comparator), else the two-key ``jnp.lexsort`` (one pass carrying 3
-    operands with a lexicographic comparator — the measurably slower
-    shape on TPU).  Both are stable, so the permutations are identical
-    element-for-element (asserted in tests/test_contraction.py).
+    One fused key when the composite key fits the widest enabled integer
+    dtype (a scalar comparator), else the two keys (a lexicographic
+    comparator — the measurably slower shape on TPU).  Both are stable, so
+    the permutations are identical element-for-element (asserted in
+    tests/test_contraction.py).
     """
-    m = ku.shape[0]
     kdt = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
     if (sentinel + 1) * (sentinel + 2) <= jnp.iinfo(kdt).max:
-        key = ku.astype(kdt) * (sentinel + 1) + kv.astype(kdt)
-        iota = jnp.arange(m, dtype=jnp.int32)
-        _, order = jax.lax.sort((key, iota), dimension=0, num_keys=1)
-        return order
-    return jnp.lexsort((kv, ku))
+        return argsort((ku.astype(kdt) * (sentinel + 1) + kv.astype(kdt),))
+    return argsort((ku, kv))
 
 
 def _contract_core(labels, edge_u, col_idx, edge_w, node_w):
@@ -67,7 +63,7 @@ def _contract_core(labels, edge_u, col_idx, edge_w, node_w):
 
     # 1. relabel-compact: cluster label -> dense coarse id
     present = jnp.zeros(n, dtype=jnp.int32).at[labels].set(1)
-    cmap = (jnp.cumsum(present) - 1).astype(idt)
+    cmap = (cumsum(present) - 1).astype(idt)
     coarse_of = cmap[labels]
     n_c = jnp.sum(present)
 
@@ -87,15 +83,13 @@ def _contract_core(labels, edge_u, col_idx, edge_w, node_w):
     sw = jnp.where(keep[order], edge_w[order], 0)
     first = run_starts2(su, sv)
     rid = run_ids(first)
-    run_w = jax.ops.segment_sum(sw, rid, num_segments=m)
+    run_w = jax.ops.segment_sum(sw, rid, num_segments=m, indices_are_sorted=True)
 
     # 4. compact valid runs to the front
     valid = first & (su < n)
-    ridx = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    pos = jnp.where(valid, ridx, m)  # out-of-range drops
-    out_u = jnp.full(m, 0, dtype=idt).at[pos].set(su, mode="drop")
-    out_v = jnp.full(m, 0, dtype=idt).at[pos].set(sv, mode="drop")
-    out_w = jnp.zeros(m, dtype=edge_w.dtype).at[pos].set(run_w[rid], mode="drop")
+    out_u = compact(su, valid, m)
+    out_v = compact(sv, valid, m)
+    out_w = compact(run_w[rid], valid, m)
     m_c = jnp.sum(valid)
 
     # coarse row_ptr over the full n-slot buffer (sliced to n_c+1 later)
@@ -106,7 +100,7 @@ def _contract_core(labels, edge_u, col_idx, edge_w, node_w):
     # only counts runs, and `where(valid, su, 0)` routes dropped runs to node 0
     # with value 0, which is harmless.
     row_ptr = jnp.concatenate(
-        [jnp.zeros(1, dtype=idt), jnp.cumsum(deg_c).astype(idt)]
+        [jnp.zeros(1, dtype=idt), cumsum(deg_c).astype(idt)]
     )
 
     # Per-level host scalars, batched: everything the orchestration loop

@@ -41,7 +41,14 @@ import jax.numpy as jnp
 
 from .bucketed_gains import bucketed_best_moves, lookup
 from .gains import best_moves
-from .segment import run_starts, segment_prefix_sum
+from .segment import (
+    cumsum,
+    row_cumsum,
+    run_starts,
+    segment_prefix_sum,
+    take_rows,
+)
+from .sort import argsort
 
 
 class LPState(NamedTuple):
@@ -110,6 +117,20 @@ _PRIO_BITS = 30  # 6 radix-32 levels resolve the threshold exactly
 # ~1 GB transient in 64-bit-weight builds (ADVICE r5 #3).  512 MB keeps the
 # int32 cutoff at the measured 2^22 boundary and halves it for int64.
 _RADIX_HIST_BYTE_LIMIT = 1 << 29
+# Largest per-level histogram the XLA auction builds, in slots.  XLA's TPU
+# compile time of a scatter-add grows with its output beyond about 2^21
+# slots (12 s at 2^22 on a v5e, 0.3 s at 2^21), so a wide label set takes
+# narrower digits and more levels; the resolved threshold is the same.
+_HIST_SLOTS = 1 << 21
+
+
+def _radix_bits(num_labels: int) -> int:
+    """Digit width of the XLA auction: the widest divisor of _PRIO_BITS up
+    to _RADIX_BITS whose histogram fits _HIST_SLOTS."""
+    for bits in (5, 3, 2):
+        if num_labels << bits <= _HIST_SLOTS:
+            return bits
+    return 1
 
 
 def use_radix_auction(num_labels: int, weight_dtype) -> bool:
@@ -123,13 +144,15 @@ def use_radix_auction(num_labels: int, weight_dtype) -> bool:
 
 def capacity_auction(
     key, movers, target, node_w, base_weights, max_weights, num_labels: int,
+    fast_compile: bool = False,
 ):
     """Strict capacity-respecting admission without a sort.
 
     Equivalent to the sorted-prefix oracle (:func:`capacity_auction_sorted`):
     each mover draws a 30-bit priority, and a per-target priority
     *threshold* is resolved radix-32 (6 levels; each level one histogram
-    segment-sum into a (num_labels, 32) table + a tiny cumsum) to the
+    segment-sum into a (num_labels, 32) table + a tiny cumsum; wide label
+    sets take narrower digits, see :func:`_radix_bits`) to the
     largest value whose admitted weight still fits
     ``max_weights[target] - base_weights[target]`` — i.e. the maximal
     random-priority prefix, computed without ordering anything.
@@ -153,6 +176,11 @@ def capacity_auction(
     Falls back to the bitwise form when num_labels is too large for the
     (num_labels * 32) per-level histogram to be worth its memory
     (> 2^22 labels; the histogram is a multi-GB transient by 2^24).
+
+    ``fast_compile`` takes the radix form whose TPU compile time does not
+    grow with ``num_labels`` (narrower digits past 2^16 labels, log-step row
+    scans, no narrow gather); the admitted set is the same.  The sharded
+    path keeps the XLA form until it has run on a real mesh.
     """
     n = movers.shape[0]
     # Upper bound (1<<30)-1, NOT 1<<30: the bitwise fallback's threshold
@@ -175,7 +203,8 @@ def capacity_auction(
             prio, movers, target, node_w, base_weights, max_weights, num_labels
         )
     return _auction_radix(
-        prio, movers, target, node_w, base_weights, max_weights, num_labels
+        prio, movers, target, node_w, base_weights, max_weights, num_labels,
+        fast_compile,
     )
 
 
@@ -194,35 +223,39 @@ def _auction_slack(movers, target, node_w, base_weights, max_weights,
 
 
 def _auction_radix(prio, movers, target, node_w, base_weights, max_weights,
-                   num_labels: int):
+                   num_labels: int, fast_compile: bool):
     """Radix-32 threshold resolution (see capacity_auction)."""
     t_idx, w_mover, slack = _auction_slack(
         movers, target, node_w, base_weights, max_weights, num_labels
     )
+    bits = _radix_bits(num_labels) if fast_compile else _RADIX_BITS
+    radix = 1 << bits
 
     def level(carry, shift):
         thr, admitted = carry
         thr_t = thr[t_idx]
-        # movers still inside the undecided window [thr, thr + 32<<shift)
+        # movers still inside the undecided window [thr, thr + radix<<shift)
         in_window = movers & (
-            (prio >> (shift + _RADIX_BITS)) == (thr_t >> (shift + _RADIX_BITS))
+            (prio >> (shift + bits)) == (thr_t >> (shift + bits))
         ) & (prio >= thr_t)
-        digit = (prio >> shift) & (_RADIX - 1)
+        digit = (prio >> shift) & (radix - 1)
+        # Movers outside the window get an out-of-range slot (dropped).
         seg = jnp.where(
-            in_window, t_idx * _RADIX + digit, num_labels * _RADIX
+            in_window, t_idx * radix + digit, num_labels * radix
         ).astype(jnp.int32)
         hist = jax.ops.segment_sum(
             jnp.where(in_window, w_mover, 0), seg,
-            num_segments=num_labels * _RADIX + 1,
-        )[:-1].reshape(num_labels, _RADIX)
-        cum = jnp.cumsum(hist, axis=1)
+            num_segments=num_labels * radix,
+        ).reshape(num_labels, radix)
+        cum = row_cumsum(hist) if fast_compile else jnp.cumsum(hist, axis=1)
         room = (slack - admitted)[:, None]
         j = jnp.sum((cum <= room) & (room >= 0), axis=1)  # digits fully admitted
-        gained = jnp.where(
-            j > 0, jnp.take_along_axis(
-                cum, jnp.maximum(j - 1, 0)[:, None], axis=1
-            )[:, 0], 0,
+        last = jnp.maximum(j - 1, 0)
+        at = (
+            take_rows(cum, last) if fast_compile
+            else jnp.take_along_axis(cum, last[:, None], axis=1)[:, 0]
         )
+        gained = jnp.where(j > 0, at, 0)
         admitted = admitted + gained
         thr = thr + (j << shift).astype(jnp.int32)
         return (thr, admitted), None
@@ -232,9 +265,7 @@ def _auction_radix(prio, movers, target, node_w, base_weights, max_weights,
     # the scan carry check).
     thr0 = jnp.zeros_like(slack, dtype=jnp.int32) * slack.astype(jnp.int32)
     adm0 = jnp.zeros_like(slack) * slack
-    shifts = jnp.arange(
-        _PRIO_BITS - _RADIX_BITS, -1, -_RADIX_BITS, dtype=jnp.int32
-    )
+    shifts = jnp.arange(_PRIO_BITS - bits, -1, -bits, dtype=jnp.int32)
     (thr, _), _ = jax.lax.scan(level, (thr0, adm0), shifts)
     return movers & (prio < thr[t_idx])
 
@@ -342,7 +373,8 @@ def _commit_moves(
     if active_prob < 1.0:
         moved = moved & jax.random.bernoulli(ka, active_prob, moved.shape)
     accept = capacity_auction(
-        kp, moved, desired, node_w, label_weights, max_label_weights, num_labels
+        kp, moved, desired, node_w, label_weights, max_label_weights,
+        num_labels, fast_compile=True,
     )
     commit = moved & accept
     new_labels = jnp.where(commit, desired, labels)
@@ -737,7 +769,7 @@ def cluster_isolated_nodes(
     cap = jnp.maximum(lookup(max_label_weights, 0), 1)  # scalar limit for clustering
     w_max = jnp.max(w)
     width = jnp.maximum(cap - w_max + 1, 1)
-    start = jnp.cumsum(w) - w
+    start = cumsum(w) - w
     bucket = jnp.where(iso, jnp.clip(start // width, 0, n - 1), n)
     bucket = bucket.astype(jnp.int32)
     ids = jnp.arange(n, dtype=labels.dtype)
@@ -834,10 +866,10 @@ def two_hop_match(
     # compile cost is amortized by the persistent cache.
     fkey = jnp.where(singleton & has, favored, n)  # sentinel: not eligible
     prio = jax.random.randint(kp, (n,), 0, jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
-    order2 = jnp.lexsort((prio, fkey))
+    order2 = argsort((fkey, prio))
     f_s = fkey[order2]
     first2 = run_starts(f_s)
-    rid2 = jnp.cumsum(first2.astype(jnp.int32)) - 1
+    rid2 = cumsum(first2.astype(jnp.int32)) - 1
     starts = jax.ops.segment_max(
         jnp.where(first2, jnp.arange(n, dtype=jnp.int32), 0), rid2, num_segments=n
     )

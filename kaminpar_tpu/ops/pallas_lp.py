@@ -30,10 +30,14 @@ edge-parallel path (they are rare and already sort-bound), mirroring the
 reference's two-phase LP split (label_propagation.h:571-601).
 
 Backend selection: ``LabelPropagationContext.lp_kernel`` = ``"xla"`` |
-``"pallas"`` | ``"auto"`` (auto = pallas on TPU backends).  Off-TPU the
-kernels run with ``interpret=True``, so tier-1 CPU tests exercise the exact
-kernel logic the TPU compiles.  On-silicon A/B is captured by
-scripts/tpu_prober.py when a TPU window opens.
+``"pallas"`` | ``"auto"``.  Off-TPU the kernels run with ``interpret=True``,
+so tier-1 CPU tests exercise the kernel logic.  They do not compile for a
+TPU with the installed jaxlib: Mosaic refuses the rate kernel's 1-D VMEM
+table gather (:data:`MOSAIC_REFUSAL`, asserted against a described v5e by
+tests/test_tpu_compile.py), and the commit kernel's histogram scatter-adds
+have no Mosaic lowering either.  So ``"auto"`` resolves to ``"xla"``, and
+``"pallas"`` on a TPU backend raises with that reason instead of failing
+mid-run.
 
 VMEM blocking notes (see TPU_NOTES.md): the label / cluster-weight /
 node-weight tables are kept VMEM-resident, which bounds the single-kernel
@@ -71,56 +75,37 @@ _BLOCK_SLOTS = 1 << 15
 _COMMIT_HIST_VMEM_BYTES = 1 << 22  # 4 MB
 
 
+#: What Mosaic reports when it compiles the rate kernel's VMEM label-table
+#: gather (``labels[cols]``) for a TPU with the installed jaxlib.
+MOSAIC_REFUSAL = "Only 2D gather is supported"
+
+
 def resolve_lp_kernel(choice: str) -> str:
     """Map the ``lp_kernel`` config knob to a concrete backend."""
     if choice not in ("xla", "pallas", "auto"):
         raise ValueError(
             f"lp_kernel must be 'xla', 'pallas' or 'auto', got {choice!r}"
         )
-    if choice == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return choice
+    if choice == "pallas" and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "lp_kernel='pallas' cannot compile for TPU: Mosaic refuses the "
+            f"fused rate kernel's VMEM table gather ({MOSAIC_REFUSAL!r}); "
+            "use lp_kernel='xla'"
+        )
+    return "xla" if choice == "auto" else choice
 
 
 def _interpret() -> bool:
-    """Interpret off-TPU so CPU CI runs the same kernel logic (dataflow,
-    masks, integer reductions) the TPU compiles."""
+    """Interpret off-TPU so CPU CI runs the kernel logic (dataflow, masks,
+    integer reductions)."""
     return jax.default_backend() != "tpu"
 
 
-def _pallas_demoted(probe: bool) -> bool:
-    """Consult the ``lp_pallas`` circuit breaker (round 17,
-    resilience/breakers.py): True when this selection must demote to the
-    XLA twins — which are bit-identical by construction, so the demotion
-    changes wall-clock, never results.
-
-    ``probe``: only a caller that guards the dispatch AND reports the
-    outcome back to the breaker (the clusterer's ``_run_iterate``) may
-    consume the half-open probe slot; unguarded callers (the refiners)
-    use pallas only while the breaker is fully closed — otherwise a
-    still-broken kernel would crash the whole partition through a probe
-    nobody catches, and a succeeding probe would never close the
-    breaker."""
-    from ..resilience.breakers import global_registry
-
-    reg = global_registry()
-    br = reg.get("lp_pallas")
-    if br.state == "closed":
-        return False
-    if probe and br.allow():
-        return False
-    reg.record_demotion("lp_pallas", "circuit breaker open")
-    return True
-
-
-def select_lp_ops(choice: str, probe: bool = False):
+def select_lp_ops(choice: str):
     """(iterate, colored_round, colored_iterate) triple for the configured
     ``lp_kernel`` knob — the single dispatch point shared by lp_clusterer /
-    lp_refiner / clp_refiner.  Breaker-aware: a non-closed ``lp_pallas``
-    breaker serves the XLA twins instead (bit-identical; demotions
-    counted, reversible via half-open probing — ``probe=True`` is
-    reserved for callers that report the outcome back)."""
-    if resolve_lp_kernel(choice) == "pallas" and not _pallas_demoted(probe):
+    lp_refiner / clp_refiner."""
+    if resolve_lp_kernel(choice) == "pallas":
         return lp_iterate_bucketed, lp_round_colored, clp_iterate_colors
     return (
         lp_ops.lp_iterate_bucketed,
@@ -541,13 +526,11 @@ def lp_iterate_compressed(
     return state
 
 
-def select_compressed_iterate(choice: str, probe: bool = False):
+def select_compressed_iterate(choice: str):
     """The compressed-stream LP sweep loop for the ``lp_kernel`` knob —
     the decode-fused dispatch point shared by the compressed clusterer
-    path and the finest-level LP refinement pass.  Breaker-aware like
-    :func:`select_lp_ops` (one ``lp_pallas`` rung covers both stream
-    variants — they share the kernel machinery that would be failing)."""
-    if resolve_lp_kernel(choice) == "pallas" and not _pallas_demoted(probe):
+    path and the finest-level LP refinement pass."""
+    if resolve_lp_kernel(choice) == "pallas":
         return lp_iterate_compressed
     return lp_ops.lp_iterate_compressed
 

@@ -8,7 +8,7 @@ execute exception, or a poisoned shape cell could still wedge the serve
 queue or silently degrade results.  This package centralizes the whole
 recovery surface:
 
-- :mod:`errors` — the typed failure taxonomy (CompileTimeout,
+- :mod:`errors` — the typed failure hierarchy (CompileTimeout,
   ExecuteFault, CapacityExceeded, BackendUnavailable, PoisonedCell,
   WorkerHung, GraphValidationError) plus :func:`errors.classify`, the ONE
   classifier every pipeline/serve dispatch site routes caught exceptions

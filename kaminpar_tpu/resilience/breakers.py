@@ -20,15 +20,16 @@ to — each demotion is counted, warned once per rung, surfaced in
 rung (primary)     demotes to      dispatch site
 =================  ==============  =====================================
 ``lanestack``      ``per-graph``   serve/engine._try_lanestacked
-``lp_pallas``      ``lp_xla``      ops/pallas_lp.select_lp_ops (+ the
-                                   clusterer's in-flight retry)
 ``device_decode``  ``dense``       graph/device_compressed gate
-``ip_device``      ``ip_host``     initial/bipartitioner pool dispatch
 ``quality_strong`` ``quality_fast``  serve engine under capacity trips
 ``cell``           ``reject``      serve admission (PoisonedCell — no
                                    silent fallback exists for an
                                    arbitrary poisoned cell)
 =================  ==============  =====================================
+
+The fused-Pallas LP kernels and the device IP pool have no rung: their
+failures raise the classified error, so a run that returns used the path
+it was configured with.
 
 Engines own a private registry (per-engine breaker state, like the
 round-6 latch); pipeline sites that run outside any engine share the
@@ -49,9 +50,7 @@ from typing import Dict, Optional, Tuple
 #: rung -> fallback (documentation + validation; README "Resilience").
 LADDER = {
     "lanestack": "per-graph",
-    "lp_pallas": "lp_xla",
     "device_decode": "dense",
-    "ip_device": "ip_host",
     "quality_strong": "quality_fast",
     "cell": "reject",
     # Fleet tier (round 18, serve/fleet.py): a replica whose watchdog
@@ -136,7 +135,7 @@ class CircuitBreaker:
                     self._probe_deadline = now + self.cooldown_s
                     return True
                 if now >= self._probe_deadline:
-                    # Stale claim — the prober vanished; re-claim.
+                    # Stale claim — the probing caller vanished; re-claim.
                     self.probes += 1
                     self._probe_deadline = now + self.cooldown_s
                     return True

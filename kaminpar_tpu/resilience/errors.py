@@ -1,4 +1,4 @@
-"""Typed failure taxonomy + the one classifier (ISSUE 13 tentpole a).
+"""Typed failure hierarchy + the one classifier.
 
 Every pipeline/serve failure routes through :func:`classify`: ad-hoc
 exceptions (jax backend errors, XLA runtime faults, allocator
@@ -20,7 +20,7 @@ from typing import Optional, Tuple
 
 
 class ResilienceError(RuntimeError):
-    """Base of the typed failure taxonomy.
+    """Base of the typed failure hierarchy.
 
     ``failure_class`` is the stable machine-readable class name (breaker
     keys, Prometheus labels, fault-plan error names); ``site`` names the
@@ -118,7 +118,7 @@ FAILURE_CLASSES = {
 
 # Message fragments that identify backend bring-up failures vs allocator
 # exhaustion inside the undifferentiated RuntimeError/XlaRuntimeError soup
-# jax raises (TPU_PROBE_LOG's init hangs + the jaxlib error strings).
+# jax raises (backend init failures + the jaxlib error strings).
 _BACKEND_MARKERS = (
     "unavailable", "failed to initialize", "no visible device",
     "backend", "failed precondition", "deadline_exceeded",

@@ -136,9 +136,6 @@ def _start_metrics_server(engine, port: int):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    from ..utils.platform import prefer_working_backend
-
-    prefer_working_backend()
     from ..presets import create_context_by_preset_name
     from .engine import PartitionEngine
 

@@ -305,9 +305,8 @@ class PartitionEngine:
         # probing), per-cell "cell" breakers (a poisoned shape cell
         # fast-fails new admissions instead of wedging the queue), and
         # per-cell "quality_strong" breakers (capacity-class failures
-        # demote strong requests to the fast tier).  Pipeline rungs
-        # (lp_pallas, ip_device, device_decode) live on the process-global
-        # registry.  The watchdog bounds hung executes.
+        # demote strong requests to the fast tier).  The pipeline rung
+        # (device_decode) lives on the process-global registry.  The watchdog bounds hung executes.
         from ..resilience.breakers import BreakerRegistry
         from ..resilience.watchdog import ExecutionWatchdog
 
@@ -1443,7 +1442,7 @@ class PartitionEngine:
                 self._execute_batch(batch)
             except Exception as exc:  # noqa: BLE001 — a poisoned batch must
                 # not kill the dispatcher; classify the failure (round 17
-                # taxonomy) and reject its requests with the typed error.
+                # hierarchy) and reject its requests with the typed error.
                 from ..resilience.errors import classify
 
                 err = classify(exc, site="dispatch")
@@ -1992,7 +1991,7 @@ class PartitionEngine:
         snap["warmup_cells"] = self.warmup_cell_counts()
         # Resilience surface (round 17): this engine's breaker registry
         # (lanestack/cell/quality rungs), the process-global pipeline
-        # registry (lp_pallas/ip_device/device_decode rungs), the
+        # registry (the device_decode rung), the
         # watchdog's guard/fire census + dossier heads, and the chaos
         # harness's injection counters.
         from ..resilience import breakers as rbreakers

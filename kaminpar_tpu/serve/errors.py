@@ -1,6 +1,6 @@
 """Error surface of the partition-serving runtime.
 
-Mirrors the error taxonomy of standard inference-serving stacks: admission
+Mirrors the error hierarchy of standard inference-serving stacks: admission
 rejection (backpressure, carries a retry-after hint), deadline expiry,
 cancellation, and engine-stopped.  All derive from :class:`ServeError` so
 callers can catch the whole family at once.
@@ -8,7 +8,7 @@ callers can catch the whole family at once.
 Round 17: these are the *control-flow* outcomes of admission and request
 lifecycle; *failures* (execute faults, compile timeouts, capacity
 exhaustion, backend loss, poisoned cells, hung workers) are typed by the
-unified taxonomy in :mod:`kaminpar_tpu.resilience.errors` — every
+unified hierarchy in :mod:`kaminpar_tpu.resilience.errors` — every
 dispatch-site ``except`` routes through ``resilience.errors.classify``
 (enforced by the kptlint ``error-discipline`` rule), and
 ``classify``/``is_control_flow`` pass this module's classes through

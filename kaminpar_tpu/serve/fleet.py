@@ -45,8 +45,8 @@ and the capacity-preflight verdict — instead of a single EMA:
   counters / ``kaminpar_fleet_scale_total``.
 
 CPU-dryrun honesty: virtual host devices SERIALIZE — a CPU fleet number
-is a router/occupancy claim, not a parallel-speedup claim; the
-device-axis throughput claim rides tpu_prober (TPU_NOTES round 18).
+is a router/occupancy claim, not a parallel-speedup claim; a
+device-axis throughput claim needs a run on chips.
 """
 
 from __future__ import annotations
@@ -232,20 +232,11 @@ class PartitionFleet:
             import jax
 
             n = len(jax.devices())
-        # One shared persistent cache dir for the whole fleet (warm-cache
-        # inheritance leg 1): resolve the base context's settings once and
-        # pin every replica to the same dir.
-        from ..context import _resolve_cache_settings
-
-        cache_enabled, cache_dir = _resolve_cache_settings(ctx.parallel)
-        self._cache_enabled, self._cache_dir = cache_enabled, cache_dir
         self._serve_overrides = dict(serve_overrides)
         self.replicas: List[PartitionEngine] = []
         for i in range(n):
             rctx = copy.deepcopy(ctx)
             rctx.parallel.placement_device = i
-            if cache_enabled and cache_dir:
-                rctx.parallel.compilation_cache_dir = cache_dir
             if rctx.serve.journal_path:
                 # Per-replica journal files (round 19): one shared path
                 # would interleave N engines' records with colliding
@@ -693,8 +684,7 @@ class PartitionFleet:
     def _spawn_replica(self) -> int:
         """Construct + start one fresh replica at the next index (caller
         holds ``_scale_lock``): same deepcopied base context, device
-        placement wrapping the mesh, the fleet's shared persistent cache
-        dir, and warm-state inheritance from the first healthy replica —
+        placement wrapping the mesh, and warm-state inheritance from the first healthy replica —
         it joins the routable set only once started (``running`` gates
         ``_replica_available``), and journals nothing until then."""
         idx = len(self.replicas)
@@ -706,8 +696,6 @@ class PartitionFleet:
             n_dev = 1
         rctx = copy.deepcopy(self.ctx)
         rctx.parallel.placement_device = idx % n_dev
-        if self._cache_enabled and self._cache_dir:
-            rctx.parallel.compilation_cache_dir = self._cache_dir
         if rctx.serve.journal_path:
             rctx.serve.journal_path += f".replica{idx}"
         eng = PartitionEngine(

@@ -1,8 +1,8 @@
 """Hang-forensics flight recorder (ISSUE 12 tentpole c).
 
-28 of 33 TPU_PROBE_LOG.jsonl records are ``init_hang_killed_after_1200s``
-with ``probe: null`` — no phase, no stack, no cause.  This module gives
-every killable measurement child a black box:
+A benchmark child killed at its deadline used to leave no phase, no stack
+and no cause behind.  This module gives every killable measurement child a
+black box:
 
 - a **daemon heartbeat thread** appends one JSON line per tick to a
   sidecar file (monotonic + wall timestamps, the current phase from the
@@ -12,15 +12,14 @@ every killable measurement child a black box:
   kill timeout dumps every thread's Python stack to a second sidecar
   moments before the kill lands;
 - :func:`read_dossier` (run by the parent AFTER the kill) assembles both
-  plus an env/backend fingerprint into the dossier
-  ``scripts/tpu_prober.py`` attaches to every killed attempt, and
-  :func:`classify_phase` maps the dying phase to the
-  init / compile / execute hang class the prober's outcome strings carry.
+  plus an env/backend fingerprint into the dossier ``bench.py`` attaches
+  to every killed child, and :func:`classify_phase` maps the dying phase
+  to an init / compile / execute hang class.
 
 The module is **pure stdlib at import time** (no jax, no package-relative
-imports) so the prober child can load it by file path and start
-heartbeating BEFORE ``import jax`` — backend-init hangs are precisely the
-case that must not escape the recorder.  The phase board is read lazily
+imports) so a child can load it by file path and start heartbeating
+BEFORE ``import jax`` — backend-init hangs are precisely the case that
+must not escape the recorder.  The phase board is read lazily
 and best-effort: until kaminpar_tpu is imported there are no phases and
 the explicit :meth:`FlightRecorder.note` marker (e.g. ``backend_init``)
 carries the attribution.
@@ -44,7 +43,7 @@ from typing import Dict, List, Optional
 #: Env fingerprint keys worth carrying in a dossier — the knobs that decide
 #: which backend a child initializes and what it would have measured.
 ENV_FINGERPRINT_KEYS = (
-    "JAX_PLATFORMS", "KAMINPAR_TPU_CACHE_DIR", "KPTPU_BENCH_SCALE",
+    "JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR", "KPTPU_BENCH_SCALE",
     "KPTPU_BENCH_FULL_SCALE", "KPTPU_BENCH_SHARD_NATIVE",
     "KAMINPAR_TPU_LANE_STACK", "KAMINPAR_TPU_DEVICE_DECODE",
 )
@@ -82,7 +81,7 @@ def _board_phases() -> Dict[str, str]:
 class FlightRecorder:
     """One heartbeat sidecar + one armed stack dump per measurement child.
 
-    Usage (the prober child)::
+    Usage (a measurement child)::
 
         rec = FlightRecorder(hb_path, interval_s=5.0,
                              stack_path=stack_path, stack_after_s=1170.0)
@@ -130,8 +129,8 @@ class FlightRecorder:
         return self
 
     def rearm_stack_dump(self, after_s: float) -> None:
-        """Re-arm the single faulthandler timer for a LATER deadline (the
-        prober re-arms once backend init succeeds: the init-phase dump
+        """Re-arm the single faulthandler timer for a LATER deadline (a
+        child re-arms once backend init succeeds: the init-phase dump
         slot no longer applies and an execute-phase hang killed at the
         attempt timeout must carry its own dying stack, not a stale
         init-era one).  Truncates the sidecar so only the newest dump
@@ -245,10 +244,10 @@ def child_sidecar_env(base_path: str, kill_after_s: float,
                       heartbeat_s: Optional[float] = None):
     """The ONE definition of the parent->child sidecar env contract
     (consumed by :func:`arm_from_env` in the child; bench's `_run_child`
-    and the prober's `run_attempt` both build it here so they can never
-    diverge).  Returns ``(env_updates, hb_path, stack_path)``; stale
-    sidecars from a previous attempt are removed.  ``attempt_after_s``
-    (the prober's post-devices_ok deadline) arms the re-arm contract."""
+    builds it here).  Returns ``(env_updates, hb_path, stack_path)``;
+    stale sidecars from a previous attempt are removed.
+    ``attempt_after_s`` (a post-backend-init deadline) arms the re-arm
+    contract."""
     hb_path = base_path + ".hb.jsonl"
     stack_path = base_path + ".stack"
     cleanup_sidecars(hb_path, stack_path)
@@ -283,8 +282,7 @@ def cleanup_sidecars(hb_path: str, stack_path: str = "") -> None:
 
 
 def classify_phase(phase: Optional[str]) -> str:
-    """Map a dying phase name to the hang class the prober's outcome
-    strings carry: ``init`` (backend/device bring-up), ``compile``
+    """Map a dying phase name to a hang class: ``init`` (backend/device bring-up), ``compile``
     (warmup/AOT/trace), ``execute`` (a real pipeline phase)."""
     p = (phase or "").lower()
     if p in ("", "startup", "backend_init", "devices", "init"):

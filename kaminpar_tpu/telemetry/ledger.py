@@ -1,8 +1,8 @@
 """Persistent run ledger + regression sentinel (round 13).
 
-Every headline number so far lives in write-once artifacts (BENCH_r0x.json,
-TPU_RESULT.json) with no machinery to compare runs over time (ROADMAP item
-5).  The ledger fixes that: each bench / prober / serve run appends ONE
+Every headline number so far lives in write-once artifacts (BENCH_r0x.json)
+with no machinery to compare runs over time (ROADMAP item 5).  The ledger
+fixes that: each bench / serve run appends ONE
 compact JSON line to ``RUNS.jsonl`` — git head, device kind, the record's
 numeric headline metrics, per-phase walls, and the sync / collective /
 compile censuses plus the kptlint summary — and ``tools regress`` compares
@@ -121,7 +121,7 @@ def _numeric_metrics(record: dict) -> Dict[str, float]:
 
 def build_entry(record: dict, *, kind: str, git_head: str = "",
                 extra: dict | None = None) -> dict:
-    """One compact ledger entry from a bench/prober/serve headline record.
+    """One compact ledger entry from a bench/serve headline record.
 
     Census snapshots come from the record when the measuring process
     embedded them (the bench children do) and fall back to this process's
@@ -245,7 +245,7 @@ def tail(n: int = 10, path: str | None = None) -> List[dict]:
 
 def record_run(record: dict, *, kind: str, git_head: str = "",
                path: str | None = None) -> Optional[str]:
-    """Build + append in one guarded step (the bench/prober entry point).
+    """Build + append in one guarded step (the bench entry point).
     Returns the path, or None when disabled (KPTPU_LEDGER=0) or failed —
     a ledger problem must never void the run's own artifact."""
     if os.environ.get("KPTPU_LEDGER", "1") == "0":

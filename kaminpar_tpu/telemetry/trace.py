@@ -13,7 +13,7 @@ collects
   the device-memory watermark (utils/heap_profiler.py), and the per-level
   quality probes (telemetry/probes.py), and
 - **quality rows** — the structured per-level records (level n/m, cut,
-  imbalance, moved counts) that bench.py / the prober embed in their JSON
+  imbalance, moved counts) that bench.py embeds in its JSON
   artifacts.
 
 The trace exports to Chrome trace-event JSON (``chrome://tracing`` /
@@ -70,7 +70,7 @@ class TraceRecorder:
         # would fail validation and mis-nest the viewer's span stacks).
         self._span_admitted: Dict[int, List[bool]] = {}
         #: structured per-level quality rows (probes.py); exported into the
-        #: trace's otherData and embedded by bench.py / the prober.
+        #: trace's otherData and embedded by bench.py.
         self.quality: List[dict] = []
         #: free-form run metadata (graph, k, preset, ...), exported verbatim.
         self.meta: Dict[str, object] = {}
@@ -296,7 +296,7 @@ class TraceRecorder:
         return path
 
     def summary(self) -> dict:
-        """Compact artifact-embeddable summary (bench.py, the prober)."""
+        """Compact artifact-embeddable summary (bench.py)."""
         with self._lock:
             events = list(self._events)
             n_quality = len(self.quality)

@@ -215,7 +215,7 @@ def watermark_backend() -> str:
 
 
 def watermark_report() -> Dict[str, object]:
-    """HBM watermark record for bench.py / the prober (ISSUE 5 satellite):
+    """HBM watermark record for bench.py:
     live bytes, the peak high-water mark, the allocator limit, and the peak's
     fraction of it — the number to cross-check against the per-chip budgets
     derived in HBM_BUDGET.md.  Every record is labeled with its measurement

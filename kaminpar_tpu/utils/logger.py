@@ -9,7 +9,7 @@ reproduced byte-compatibly by :func:`log_result_line`.
 Structured mode (ISSUE 5 satellite): ``KAMINPAR_TPU_LOG=json`` switches every
 line to a one-object-per-line JSON record (``{"ts", "level", "msg", ...}``;
 the RESULT line additionally carries its fields as ``"event": "result"``)
-so prober and serve logs are machine-parseable.  Default plain-text output
+so bench and serve logs are machine-parseable.  Default plain-text output
 is byte-identical to before.
 """
 

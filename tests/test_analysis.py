@@ -162,11 +162,11 @@ def test_runtime_rule_bans_process_default_mutators():
     findings = analyze(
         """
         from ..graph.csr import set_layout_build_mode
-        from ..context import configure_compilation_cache
+        from ..utils.timer import set_sync_mode
 
         def misconfigure(ctx):
             set_layout_build_mode("device")
-            configure_compilation_cache(ctx.parallel)
+            set_sync_mode(True)
         """,
         rel="kaminpar_tpu/serve/_snippet.py",
     )

@@ -1,7 +1,7 @@
 """Executable-grade observability (ISSUE 12): capacity planner
 predicted-vs-measured validation, executable-census neutrality, serve
-admission preflight, and the hang-forensics flight recorder / prober
-dossier round trip."""
+admission preflight, and the hang-forensics flight recorder's dossier
+round trip."""
 
 import json
 import os
@@ -291,63 +291,6 @@ def test_classify_phase():
     assert flight_recorder.classify_phase("lp_refinement") == "execute"
 
 
-def _load_prober():
-    import importlib
-
-    scripts = os.path.join(REPO, "scripts")
-    if scripts not in sys.path:
-        sys.path.insert(0, scripts)
-    import tpu_prober
-
-    importlib.reload(tpu_prober)
-    return tpu_prober
-
-
-def test_forced_hang_attempt_carries_dossier(tmp_path, monkeypatch):
-    """ISSUE 12 acceptance: a killed prober attempt in a forced-hang run
-    carries a non-null dossier with phase + stack tail, and the outcome is
-    classified by the dying phase."""
-    prober = _load_prober()
-    monkeypatch.setenv("KPTPU_PROBER_TEST_HANG", "init")
-    monkeypatch.setenv("KPTPU_HEARTBEAT_S", "0.2")
-    monkeypatch.setattr(prober, "WORK_DIR", str(tmp_path))
-    monkeypatch.setattr(prober, "LOG_PATH", str(tmp_path / "probe.jsonl"))
-    monkeypatch.setattr(prober, "INIT_TIMEOUT_S", 4.0)
-    monkeypatch.setattr(prober, "ATTEMPT_TIMEOUT_S", 30.0)
-    rec = prober.run_attempt(1)
-    assert rec is None
-    lines = [
-        json.loads(ln)
-        for ln in (tmp_path / "probe.jsonl").read_text().splitlines()
-    ]
-    attempt = next(r for r in lines if r.get("attempt") == 1)
-    assert attempt["outcome"].startswith("init_hang_killed_after_")
-    dossier = attempt["dossier"]
-    assert dossier is not None
-    assert dossier["phase"] == "backend_init"
-    assert dossier["phase_class"] == "init"
-    assert dossier["heartbeats"] >= 1
-    # The armed faulthandler dump fired before the kill: the stack tail
-    # shows the sleep the child was wedged in.
-    assert any("sleep" in ln or "child_attempt" in ln
-               for ln in dossier.get("stack_tail", [])), dossier
-    # Scratch sidecars are cleaned up after the dossier is read.
-    assert not list(tmp_path.glob(".tpu_probe_attempt_*"))
-
-
-def test_retry_sleep_escalation():
-    prober = _load_prober()
-    base = prober.RETRY_SLEEP_S
-    assert prober.retry_sleep_for(0) == base
-    assert prober.retry_sleep_for(2) == base
-    assert prober.retry_sleep_for(3) == min(2 * base, prober.RETRY_SLEEP_MAX_S)
-    assert prober.retry_sleep_for(4) == min(4 * base, prober.RETRY_SLEEP_MAX_S)
-    # Bounded: a week of hangs still sleeps at most the cap.
-    assert prober.retry_sleep_for(50) == max(
-        prober.RETRY_SLEEP_MAX_S, base
-    )
-
-
 # -- tools CLI ----------------------------------------------------------------
 
 
@@ -372,39 +315,6 @@ def test_tools_capacity_cli_json(capsys):
     payload = json.loads(capsys.readouterr().out)
     assert payload["max_feasible_scale"]["dense"] is not None
     assert payload["rows"][0]["dense"]["predicted_peak_bytes"] > 0
-
-
-def test_tools_doctor_cli(tmp_path, capsys):
-    from kaminpar_tpu.tools.tools import doctor
-
-    log = tmp_path / "probe.jsonl"
-    records = [
-        {"event": "prober_start"},
-        {"attempt": 1, "outcome": "init_hang_killed_after_1200s",
-         "probe": None,
-         "dossier": {"phase": "backend_init", "phase_class": "init",
-                     "heartbeats": 99,
-                     "last_heartbeat": {"rss_bytes": 123},
-                     "stack_tail": ["File x", "  time.sleep(1)"]}},
-        {"attempt": 2, "outcome": "init_hang_killed_after_1200s",
-         "probe": None},
-        {"attempt": 3, "outcome": "measured",
-         "probe": {"probe": "devices_ok", "init_s": 42.0}},
-    ]
-    log.write_text("".join(json.dumps(r) + "\n" for r in records))
-    rc = doctor([str(log)])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "init_hang_killed_after_1200s: 2" in out
-    assert "backend_init: 1" in out
-    assert "(no dossier): 1" in out
-    assert "time.sleep(1)" in out
-    rc = doctor([str(log), "--json"])
-    assert rc == 0
-    payload = json.loads(capsys.readouterr().out)
-    assert payload["attempts"] == 3
-    assert payload["hang_phases"]["backend_init"] == 1
-    assert payload["init_s"]["mean"] == 42.0
 
 
 # -- ledger integration -------------------------------------------------------

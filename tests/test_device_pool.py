@@ -74,6 +74,40 @@ def test_device_pool_deterministic_and_feasible():
     assert not np.array_equal(l1, l3)
 
 
+@pytest.mark.parametrize("make", [
+    lambda: generators.rmat_graph(7, 8, seed=2),
+    lambda: generators.star_graph(40),
+], ids=["rmat", "star"])
+def test_connections_equal_segment_sums(make):
+    """The pool's prefix-sum row sums equal scatter-add segment sums over
+    the padded CSR (pad rows and the anchor's pad edges included), and the
+    cut the FM loop scores from them equals the edge-wise cut."""
+    import jax.numpy as jnp
+
+    from kaminpar_tpu.graph.csr import from_numpy_csr
+
+    host = graph_to_host(make())
+    pv = from_numpy_csr(
+        host.row_ptr, host.col_idx, host.node_w, host.edge_w
+    ).padded()
+    rng = np.random.default_rng(0)
+    in0 = jnp.asarray(rng.random(pv.n_pad) < 0.5)
+    conn0, conn1 = bip._connections(
+        in0, pv.edge_u, pv.col_idx, pv.edge_w, pv.n_pad
+    )
+    to0 = jax.ops.segment_sum(
+        jnp.where(in0[pv.col_idx], pv.edge_w, 0), pv.edge_u,
+        num_segments=pv.n_pad,
+    )
+    degw = jax.ops.segment_sum(pv.edge_w, pv.edge_u, num_segments=pv.n_pad)
+    np.testing.assert_array_equal(np.asarray(conn0), np.asarray(to0))
+    np.testing.assert_array_equal(np.asarray(conn1), np.asarray(degw - to0))
+    both_ends = jnp.sum(
+        jnp.where(in0[pv.edge_u] != in0[pv.col_idx], pv.edge_w, 0)
+    )
+    assert int(jnp.sum(jnp.where(in0, conn1, conn0))) == int(both_ends)
+
+
 def test_lane_results_vmap_scan_loop_identical():
     """The single-lane kernel produces bit-identical partitions whether the
     lane stack executes as vmap, scan, or a Python loop — the ROADMAP's
@@ -222,6 +256,22 @@ def test_device_pool_rejects_unsafe_weights():
     big = host._replace(node_w=np.full(4, 2**30, dtype=np.int64))
     with pytest.raises(ValueError):
         _device_pool(big, np.array([2**33, 2**33], dtype=np.int64), seed=0)
+
+
+def test_too_wide_bisection_counts_host_fallback():
+    """Weights past int32 resolve to the device pool but run on the host
+    pool; the pool census counts each such bisection."""
+    host = graph_to_host(generators.path_graph(6))
+    big = host._replace(node_w=np.full(6, 2**30, dtype=np.int64))
+    mw = np.array([2**32, 2**32], dtype=np.int64)
+    bip.reset_pool_stats()
+    labels = multilevel_bipartition(big, mw, np.random.default_rng(0), DEVICE_IPC)
+    snap = bip.pool_stats_snapshot()
+    assert snap["fallbacks"] == 1 and snap["calls"] == 0
+    assert set(np.unique(labels)) <= {0, 1} and len(labels) == 6
+    multilevel_bipartition(host, _budgets(host), np.random.default_rng(0), DEVICE_IPC)
+    snap = bip.pool_stats_snapshot()
+    assert snap["fallbacks"] == 1 and snap["calls"] == 1
 
 
 def test_deep_pipeline_device_backend_deterministic_and_budgeted():

@@ -108,7 +108,7 @@ def test_baseline_window_filters_kind_backend_and_workload():
     entries = [
         ledger.build_entry(_record(backend="cpu-fallback"), kind="bench"),
         ledger.build_entry(_record(backend="tpu"), kind="bench"),
-        ledger.build_entry(_record(backend="cpu-fallback"), kind="prober"),
+        ledger.build_entry(_record(backend="cpu-fallback"), kind="serve"),
         ledger.build_entry(_record(backend="cpu-fallback"), kind="bench"),
         # same kind/backend but a DIFFERENT workload scale: not a baseline
         ledger.build_entry(
@@ -199,43 +199,6 @@ def test_record_run_kill_switch(tmp_path, monkeypatch):
     assert len(ledger.read(path)) == 1
 
 
-# -- bench salvage compression (satellite) -----------------------------------
-
-
-def test_probe_telemetry_compresses_attempts(tmp_path, monkeypatch):
-    """The prober summary embeds OUTCOME COUNTS (plus the 6h failure-window
-    count the inline-probe decision needs) instead of the full per-attempt
-    list that dominated BENCH_r05's tail."""
-    import time as _time
-
-    import bench
-
-    log = tmp_path / "TPU_PROBE_LOG.jsonl"
-    now = _time.time()
-    rows = [{"event": "prober_start"}]
-    for i in range(40):
-        rows.append({"attempt": i + 1, "ts": now - 3600 * 10,
-                     "iso": "old", "outcome": "init_hang_killed_after_1200s"})
-    rows.append({"attempt": 41, "ts": now - 60, "iso": "new",
-                 "outcome": "init_hang_killed_after_1201s"})
-    rows.append({"attempt": 42, "ts": now - 30, "iso": "newer",
-                 "outcome": "ambient_is_cpu"})
-    log.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    monkeypatch.setattr(bench, "TPU_PROBE_LOG", str(log))
-
-    summary = bench.probe_telemetry()
-    assert summary["attempts"] == 42
-    assert summary["outcomes"]["init_hang_killed_after_1200s"] == 40
-    assert "attempt_records" not in summary  # the compression satellite
-    # only the two attempts inside the 6h window count as recent failures
-    assert summary["recent_failed_6h"] == 2
-    assert bench._recent_failures(summary) == 2
-    assert bench._recent_failures(None) == 0
-    assert summary["last_outcome"] == "ambient_is_cpu"
-    # the summary is fixed-size: growing the log 10x must not grow it
-    assert len(json.dumps(summary)) < 2000
-
-
 # -- git head resolution (round 20 satellite) --------------------------------
 
 
@@ -320,7 +283,7 @@ def test_build_report_groups_and_markdown(tmp_path):
         ledger.append(entry, path)
     # a second, quiet group with a different kind
     for _ in range(3):
-        ledger.append(ledger.build_entry(_record(), kind="prober"), path)
+        ledger.append(ledger.build_entry(_record(), kind="serve"), path)
 
     rep = ledger.build_report(path=path)
     assert rep["summary"]["entries"] == 9
@@ -329,19 +292,19 @@ def test_build_report_groups_and_markdown(tmp_path):
     assert rep["summary"]["total_regressions"] >= 1
     bench_row = next(r for r in rep["groups"] if r["kind"] == "bench")
     assert bench_row["regressions"] and bench_row["attribution"]
-    prober_row = next(r for r in rep["groups"] if r["kind"] == "prober")
-    assert not prober_row["regressions"]
+    serve_row = next(r for r in rep["groups"] if r["kind"] == "serve")
+    assert not serve_row["regressions"]
 
     md = ledger.render_report_markdown(rep)
     assert "# Ledger report" in md
-    assert "## bench" in md and "## prober" in md
+    assert "## bench" in md and "## serve" in md
     assert "partition_wall_s" in md
     assert "suspect phase.partitioning_s" in md
 
     # kind filter narrows the report
-    only = ledger.build_report(path=path, kinds=["prober"])
+    only = ledger.build_report(path=path, kinds=["serve"])
     assert only["summary"]["groups"] == 1
-    assert only["groups"][0]["kind"] == "prober"
+    assert only["groups"][0]["kind"] == "serve"
 
 
 def test_tools_report_cli_and_regress_summary(tmp_path, capsys):

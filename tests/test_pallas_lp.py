@@ -203,10 +203,20 @@ def test_clusterer_backend_switch_bit_identical():
 def test_resolve_lp_kernel():
     assert pallas_lp.resolve_lp_kernel("xla") == "xla"
     assert pallas_lp.resolve_lp_kernel("pallas") == "pallas"
-    # CPU test environment: auto falls back to the XLA lowering.
-    assert pallas_lp.resolve_lp_kernel("auto") in ("xla", "pallas")
+    # auto is the XLA lowering on every backend: the fused kernels do not
+    # compile for a TPU (tests/test_tpu_compile.py).
+    assert pallas_lp.resolve_lp_kernel("auto") == "xla"
     with pytest.raises(ValueError, match="lp_kernel"):
         pallas_lp.resolve_lp_kernel("mosaic")
+
+
+def test_resolve_lp_kernel_pallas_on_tpu_raises(monkeypatch):
+    """On a TPU backend, "pallas" raises with the compiler's reason
+    instead of failing mid-run (auto stays on XLA)."""
+    monkeypatch.setattr(pallas_lp.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match=pallas_lp.MOSAIC_REFUSAL):
+        pallas_lp.resolve_lp_kernel("pallas")
+    assert pallas_lp.resolve_lp_kernel("auto") == "xla"
 
 
 def test_lp_kernel_config_roundtrip():
@@ -326,37 +336,6 @@ def test_vcycle_shape_bucket_count_small():
     LP/contraction shape buckets."""
     snap, _ = _run_vcycle(11)
     assert snap.get("padded_bucket", 0) <= 12, snap
-
-
-def test_pallas_round_tpu_lowering(monkeypatch):
-    """Mosaic TPU-lowering frontier for the fused round (compiled path, not
-    interpret).  On this jaxlib generation Pallas TPU lowering lacks the
-    dynamic `gather` primitive the VMEM label lookup needs, so the export
-    xfails with that exact signal; on toolchains that implement it
-    (tpu.DynamicGatherOp) this test asserts the whole round lowers, so
-    first silicon contact measures instead of debugging."""
-    from jax import export as jexport
-
-    monkeypatch.setattr(pallas_lp, "_interpret", lambda: False)
-    g = generators.rmat_graph(8, 8, seed=2)
-    pv, bv, state = _init(g)
-    max_w = jnp.asarray(30, dtype=pv.row_ptr.dtype)
-
-    def f(state, key):
-        return pallas_lp.lp_round_bucketed(
-            state, key, bv.buckets, bv.heavy, bv.gather_idx, pv.node_w,
-            max_w, num_labels=pv.n_pad,
-        )
-
-    try:
-        exp = jexport.export(jax.jit(f), platforms=("tpu",))(
-            state, jax.random.PRNGKey(0)
-        )
-    except NotImplementedError as e:
-        pytest.xfail(f"Mosaic lowering gap on this jaxlib: {e}")
-    except Exception as e:  # noqa: BLE001 - lowering infra varies by version
-        pytest.xfail(f"TPU export unavailable here: {type(e).__name__}: {e}")
-    assert len(exp.serialize()) > 0
 
 
 @pytest.mark.slow

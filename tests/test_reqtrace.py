@@ -296,8 +296,17 @@ def test_journal_replay_trace_continuity(tmp_path):
     e2.start(warmup=False)
     try:
         deadline = time.monotonic() + 180
-        while (J.read_journal(str(path))["unresolved"]
-               and time.monotonic() < deadline):
+
+        def settled():
+            # The journal's resolution record is written before the trace
+            # records the resolve event: wait for both.
+            if J.read_journal(str(path))["unresolved"]:
+                return False
+            dossiers = [e2.reqtrace.dossier(tid) for tid in tids]
+            return all(d is not None and d["summary"]["resolved"]
+                       for d in dossiers)
+
+        while not settled() and time.monotonic() < deadline:
             time.sleep(0.1)
         assert not J.read_journal(str(path))["unresolved"]
         for tid in tids:

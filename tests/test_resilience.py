@@ -1,4 +1,4 @@
-"""Unified resilience layer tests (ISSUE 13): the typed taxonomy + the
+"""Unified resilience layer tests: the typed hierarchy + the
 ONE classifier, the seed-keyed fault-injection harness, the circuit
 breaker state machine, and the chaos matrix the acceptance criteria
 name — for every fault class x injection point, the engine recovers
@@ -72,7 +72,7 @@ def _engine(threshold=3, cooldown=30.0, execute_timeout=0.0, **serve):
 
 
 # ---------------------------------------------------------------------------
-# Taxonomy + classifier
+# Hierarchy + classifier
 # ---------------------------------------------------------------------------
 
 
@@ -102,7 +102,7 @@ def test_classify_idempotent_and_control_flow_passthrough():
     full = QueueFullError(0.5)
     assert is_control_flow(full)
     assert not is_control_flow(RuntimeError("boom"))
-    # The serve CapacityError (round 16 preflight) wraps into the taxonomy.
+    # The serve CapacityError (capacity preflight) wraps into the hierarchy.
     from kaminpar_tpu.serve.errors import CapacityError
 
     wrapped = classify(CapacityError(100, 10))
@@ -342,30 +342,6 @@ def test_breaker_would_allow_peek_vs_claim():
     assert br.probes == 2
 
 
-def test_lp_pallas_probe_reserved_for_guarded_callers():
-    """Only probe=True callers (the clusterer's guarded dispatch) may
-    consume the lp_pallas half-open probe: an unguarded refiner handed a
-    still-broken pallas kernel would crash the whole partition with
-    nobody reporting the probe outcome back."""
-    from kaminpar_tpu.ops import lp as lp_ops
-    from kaminpar_tpu.ops.pallas_lp import select_lp_ops
-
-    reg = rbreakers.global_registry()
-    br = reg.get("lp_pallas")
-    br.threshold = 1
-    br.cooldown_s = 0.1
-    br.record_failure()
-    time.sleep(0.11)
-    # Unguarded selection (refiners): demoted to XLA, probe NOT consumed.
-    ops = select_lp_ops("pallas")
-    assert ops[0] is lp_ops.lp_iterate_bucketed
-    assert br.state == "open" and br.probes == 0
-    # Guarded selection (clusterer): granted the probe.
-    ops = select_lp_ops("pallas", probe=True)
-    assert ops[0] is not lp_ops.lp_iterate_bucketed
-    assert br.state == "half-open" and br.probes == 1
-
-
 def test_engine_shutdown_disarms_its_fault_plan():
     """A fault plan armed from the engine's context must not outlive the
     engine — injections leaking into unrelated pipelines in the process
@@ -543,29 +519,19 @@ def test_chaos_lanestack_demotion_bit_identical_and_restore():
 
 
 def test_chaos_ip_device_demotion_bit_identical():
-    """With every device-pool dispatch faulted, the run demotes to the
-    host pool — bit-identical to a run configured ip_backend="host"
-    from the start (the injection fires before the device path draws
-    from the host RNG stream), and counted on the global registry."""
-    import warnings
-
-    def run(backend, inject):
-        ctx = create_context_by_preset_name("default")
-        ctx.initial_partitioning.ip_backend = backend
-        solver = KaMinPar(ctx)
-        solver.set_graph(_rmat(seed=5, scale=7))
-        if inject:
-            with injected_faults("execute@ip_device:execute-fault:n=0"):
-                with warnings.catch_warnings():
-                    warnings.simplefilter("ignore")
-                    return solver.compute_partition(4, 0.03)
-        return solver.compute_partition(4, 0.03)
-
-    host = run("host", inject=False)
-    demoted = run("device", inject=True)
-    assert np.array_equal(host, demoted)
+    """A faulted device-pool dispatch is not rescued by the host pool:
+    the facade raises the typed error and records no demotion — a run
+    that returns used the backend it resolved."""
+    ctx = create_context_by_preset_name("default")
+    ctx.initial_partitioning.ip_backend = "device"
+    solver = KaMinPar(ctx)
+    solver.set_graph(_rmat(seed=5, scale=7))
+    with injected_faults("execute@ip_device:execute-fault:n=0"):
+        with pytest.raises(ExecuteFault) as ei:
+            solver.compute_partition(4, 0.03)
+    assert ei.value.injected and ei.value.site == "ip_device"
     demos = rbreakers.global_registry().snapshot()["demotions"]
-    assert demos.get("ip_device", 0) >= 1
+    assert "ip_device" not in demos
 
 
 def test_chaos_device_decode_demotion_bit_identical():
@@ -595,43 +561,36 @@ def test_chaos_device_decode_demotion_bit_identical():
 
 
 def test_chaos_pallas_demotion_bit_identical():
-    """A faulted Pallas LP dispatch retries in-flight on the XLA twin
-    (bit-identical by the round-5 contract) and records the failure on
-    the lp_pallas breaker; with the breaker tripped, later selections
-    demote at the dispatch point."""
-    import warnings
+    """The healthy Pallas path (interpret mode off-TPU) is bit-identical
+    to XLA, and a faulted Pallas LP dispatch raises the typed error
+    instead of rerunning on the XLA twin — no demotion is recorded."""
 
-    def run(kernel, inject):
+    def solver(kernel):
         ctx = create_context_by_preset_name("default")
         ctx.coarsening.lp.lp_kernel = kernel
         ctx.refinement.lp.lp_kernel = kernel
         # Engage coarsening at small n (the clusterer owns the pallas
-        # dispatch + in-flight retry); the default C=2000 would skip LP
-        # clustering entirely at this scale.
+        # dispatch); the default C=2000 would skip LP clustering entirely
+        # at this scale.
         ctx.coarsening.contraction_limit = 10
-        solver = KaMinPar(ctx)
-        solver.set_graph(_rmat(seed=8, scale=6))
-        if inject:
-            with injected_faults("execute@lp_pallas:execute-fault:n=1"):
-                with warnings.catch_warnings():
-                    warnings.simplefilter("ignore")
-                    return solver.compute_partition(2, 0.03)
-        return solver.compute_partition(2, 0.03)
+        s = KaMinPar(ctx)
+        s.set_graph(_rmat(seed=8, scale=6))
+        return s
 
-    xla = run("xla", inject=False)
-    demoted = run("pallas", inject=True)
-    assert np.array_equal(xla, demoted)
-    reg = rbreakers.global_registry()
-    br = reg.get("lp_pallas").snapshot()
-    assert br["failures"] == 1
-    assert reg.snapshot()["demotions"].get("lp_pallas", 0) >= 1
+    xla = solver("xla").compute_partition(2, 0.03)
+    assert np.array_equal(xla, solver("pallas").compute_partition(2, 0.03))
+    with injected_faults("execute@lp_pallas:execute-fault:n=1"):
+        with pytest.raises(ExecuteFault) as ei:
+            solver("pallas").compute_partition(2, 0.03)
+    assert ei.value.injected and ei.value.site == "lp_pallas"
+    assert "lp_pallas" not in rbreakers.global_registry().snapshot()[
+        "demotions"
+    ]
 
 
-def test_pallas_retry_survives_donated_state():
-    """The iterate twins donate their state carry: a pallas failure AFTER
-    dispatch has consumed the buffer, so the in-flight XLA retry must run
-    from a pre-attempt copy — re-passing the donated state would die on
-    'Array has been deleted' instead of recovering."""
+def test_pallas_dispatch_failure_raises_classified():
+    """A Pallas failure after dispatch surfaces classified, with the
+    kernel's own exception chained as the cause."""
     import jax.numpy as jnp
 
     from kaminpar_tpu.coarsening.lp_clusterer import LPClustering
@@ -643,15 +602,14 @@ def test_pallas_retry_survives_donated_state():
         return state + inc
 
     def pallas_it(state, inc):
-        state.delete()  # emulate donation consuming the buffer...
         raise RuntimeError("pallas died after dispatch")
 
-    out = clus._run_iterate(
-        pallas_it, xla_it, jnp.arange(4), jnp.int32(1)
-    )
+    with pytest.raises(ExecuteFault) as ei:
+        clus._run_iterate(pallas_it, xla_it, jnp.arange(4), jnp.int32(1))
+    assert isinstance(ei.value.__cause__, RuntimeError)
+    assert ei.value.site == "lp_pallas"
+    out = clus._run_iterate(xla_it, xla_it, jnp.arange(4), jnp.int32(1))
     assert np.array_equal(np.asarray(out), np.arange(4) + 1)
-    br = rbreakers.global_registry().get("lp_pallas").snapshot()
-    assert br["failures"] == 1
 
 
 def test_halfopen_cell_probe_served_stacked_closes_breaker():
