@@ -78,7 +78,6 @@ def run_lp_phase() -> dict:
     from kaminpar_tpu.utils import RandomState, next_key
     from kaminpar_tpu.utils import compile_stats, sync_stats
 
-    compile_stats.enable_compile_time_tracking()
     compile_stats.reset()
     sync_stats.reset()
 
@@ -243,7 +242,6 @@ def run_full_phase(record: dict | None = None) -> dict:
 
     from kaminpar_tpu.utils import compile_stats, sync_stats
 
-    compile_stats.enable_compile_time_tracking()
     compile_stats.reset()
     sync_stats.reset()
 

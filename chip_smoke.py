@@ -213,7 +213,6 @@ def run_one_chip(scale: int, k: int = K) -> None:
     from kaminpar_tpu.telemetry import capacity
     from kaminpar_tpu.utils import compile_stats, sync_stats
 
-    compile_stats.enable_compile_time_tracking()
     dev = jax.devices()[0]
     t0 = time.perf_counter()
     g, *host = make_graph(scale)

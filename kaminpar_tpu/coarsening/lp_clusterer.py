@@ -171,6 +171,7 @@ class LPClustering:
             active_prob=active_prob,
             tie_break=self.ctx.tie_breaking.value,
         )
+        lp.note_rounds(state)
 
         if self.ctx.cluster_isolated_nodes:
             state = lp.cluster_isolated_nodes(
@@ -242,6 +243,7 @@ class LPClustering:
             active_prob=active_prob,
             tie_break=self.ctx.tie_breaking.value,
         )
+        lp.note_rounds(state)
 
         if self.ctx.cluster_isolated_nodes:
             state = lp.cluster_isolated_nodes(

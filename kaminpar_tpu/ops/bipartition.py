@@ -492,27 +492,30 @@ def pool_bipartition_device(
     target = min(mw0, share)
 
     t0 = time.perf_counter()
-    g = from_numpy_csr(row_ptr, col_idx, node_w, edge_w)
-    # Pin the owning engine's layout mode through the EngineRuntime
-    # accessor: this runs on extension pool workers where thread-local
-    # activation is otherwise invisible (kptlint runtime-isolation; the
-    # pool submission sites wrap workers in context.propagate_runtime, and
-    # the pin keeps the graph correct even if it outlives the activation).
-    from ..context import current_runtime
+    # The pool call's host span on the profiler's clock (no timer scope:
+    # it runs inside initial partitioning's, on extension workers too).
+    with jax.profiler.TraceAnnotation("kp/ip_pool"):
+        g = from_numpy_csr(row_ptr, col_idx, node_w, edge_w)
+        # Pin the owning engine's layout mode through the EngineRuntime
+        # accessor: this runs on extension pool workers where thread-local
+        # activation is otherwise invisible (kptlint runtime-isolation; the
+        # pool submission sites wrap workers in context.propagate_runtime, and
+        # the pin keeps the graph correct even if it outlives the activation).
+        from ..context import current_runtime
 
-    rt = current_runtime()
-    g._layout_mode = rt.layout_build if rt is not None else None
-    pv = g.padded()
-    idt = pv.node_w.dtype
-    keys = method_lane_keys(seed, methods)
-    packed = _pool_kernel(
-        keys, pv.edge_u, pv.col_idx, pv.edge_w, pv.node_w,
-        jnp.asarray(n, dtype=idt), jnp.asarray(target, dtype=idt),
-        jnp.asarray(mw0, dtype=idt), jnp.asarray(mw1, dtype=idt),
-        methods=methods, grow_trips=grow_trip_count(pv.n_pad),
-        fm_rounds=fm_round_count(pv.n_pad, ipc.fm_num_iterations),
-    )
-    host = sync_stats.pull(packed)  # THE bisection readback
+        rt = current_runtime()
+        g._layout_mode = rt.layout_build if rt is not None else None
+        pv = g.padded()
+        idt = pv.node_w.dtype
+        keys = method_lane_keys(seed, methods)
+        packed = _pool_kernel(
+            keys, pv.edge_u, pv.col_idx, pv.edge_w, pv.node_w,
+            jnp.asarray(n, dtype=idt), jnp.asarray(target, dtype=idt),
+            jnp.asarray(mw0, dtype=idt), jnp.asarray(mw1, dtype=idt),
+            methods=methods, grow_trips=grow_trip_count(pv.n_pad),
+            fm_rounds=fm_round_count(pv.n_pad, ipc.fm_num_iterations),
+        )
+        host = sync_stats.pull(packed)  # THE bisection readback
     wall = time.perf_counter() - t0
 
     labels = host[:n].astype(np.int32)

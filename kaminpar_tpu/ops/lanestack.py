@@ -108,8 +108,8 @@ def _masked_iterate(state, key, buckets, heavy, gather_idx, node_w, max_w,
         return i + 1, st
 
     state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state
+    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
+    return state._replace(rounds=rounds)
 
 
 # ---------------------------------------------------------------------------

@@ -33,11 +33,13 @@ and edge weight must stay below 2^31.  The 64-bit mode enables jax x64.
 
 from __future__ import annotations
 
+import threading
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .bucketed_gains import bucketed_best_moves, lookup
 from .gains import best_moves
@@ -55,6 +57,62 @@ class LPState(NamedTuple):
     labels: jax.Array  # (n,) current label per node
     label_weights: jax.Array  # (num_labels,) total node weight per label
     num_moved: jax.Array  # () int32 — nodes moved in the last round
+    rounds: jax.Array = np.int32(0)  # () int32 — rounds the last sweep loop ran
+
+
+# -- LP round counter ---------------------------------------------------------
+#
+# The sweep loops run on device, so their round counts are device scalars.
+# note_rounds keeps them unread; lp_stats_snapshot reads them.  The partition
+# path therefore gets no readback and no dispatch from the count.  Past
+# _FOLD_AT pending scalars, those already computed are folded into the host
+# total, so a long-lived process holds a bounded list and never waits.
+
+_FOLD_AT = 1024
+_lp_stats_lock = threading.Lock()
+_lp_stats = {"calls": 0, "rounds": 0}
+_pending_rounds: list = []
+
+
+def note_rounds(state: LPState) -> LPState:
+    """Count one sweep loop (``lp_iterate_*``) and keep its ``rounds``
+    unread until :func:`lp_stats_snapshot`; returns ``state``."""
+    done = []
+    with _lp_stats_lock:
+        _lp_stats["calls"] += 1
+        _pending_rounds.append(state.rounds)
+        if len(_pending_rounds) >= _FOLD_AT:
+            ready = [r.is_ready() for r in _pending_rounds]
+            done = [r for r, ok in zip(_pending_rounds, ready) if ok]
+            _pending_rounds[:] = [r for r, ok in zip(_pending_rounds, ready) if not ok]
+    if done:
+        # Finished values: copying them waits for nothing, and leaving them
+        # out of sync_stats keeps a partition's count independent of how
+        # many loops the process ran before it.
+        with jax.transfer_guard_device_to_host("allow"):
+            folded = int(np.sum(jax.device_get(done)))  # kpt: ignore[sync-discipline]
+        with _lp_stats_lock:
+            _lp_stats["rounds"] += folded
+    return state
+
+
+def lp_stats_snapshot() -> dict:
+    """{calls, rounds}: sweep loops counted by :func:`note_rounds` and the
+    LP rounds they ran.  Reads the pending device scalars, one counted
+    transfer each (phase ``lp_stats``), and waits for the loops still
+    running; call it outside the timed path."""
+    from ..utils import sync_stats
+
+    with _lp_stats_lock:
+        pending = list(_pending_rounds)
+        _pending_rounds.clear()
+    folded = 0
+    if pending:
+        host = sync_stats.pull(*pending, phase="lp_stats")
+        folded = int(np.sum(host if len(pending) > 1 else [host]))
+    with _lp_stats_lock:
+        _lp_stats["rounds"] += folded
+        return dict(_lp_stats)
 
 
 def num_labels_bucket(k: int, floor: int = 64) -> int:
@@ -357,7 +415,7 @@ def _commit_moves(
     combined with ``active_prob`` < 1 to stay oscillation-safe under
     synchronous commits.  Clustering keeps strict stickiness.
     """
-    labels, label_weights, _ = state
+    labels, label_weights = state.labels, state.label_weights
     kp, ka, kt = jax.random.split(kp, 3)
     better = tconn > own_conn
     if allow_tie_moves:
@@ -557,8 +615,8 @@ def lp_iterate_bucketed(
         return i + 1, st
 
     state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state
+    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
+    return state._replace(rounds=rounds)
 
 
 # ---------------------------------------------------------------------------
@@ -711,8 +769,8 @@ def lp_iterate_compressed(
         return i + 1, st
 
     state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state
+    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
+    return state._replace(rounds=rounds)
 
 
 @partial(jax.jit, static_argnames=("num_labels",))
@@ -761,7 +819,7 @@ def cluster_isolated_nodes(
     boundary, so no cluster exceeds the limit.  Slightly more fragmented than
     the reference's sequential greedy packing, never overweight.
     """
-    labels, _, num_moved = state
+    labels, num_moved = state.labels, state.num_moved
     n = labels.shape[0]
     deg = row_ptr[1:] - row_ptr[:-1]
     iso = (deg == 0) & (node_w > 0)  # weight-0 degree-0 nodes are shape padding
@@ -843,7 +901,8 @@ def two_hop_match(
     *,
     num_labels: int,
 ) -> LPState:
-    labels, label_weights, num_moved = state
+    labels, label_weights = state.labels, state.label_weights
+    num_moved = state.num_moved
     n = labels.shape[0]
 
     # Singleton = node alone in its own cluster.

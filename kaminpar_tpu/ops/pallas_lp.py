@@ -522,8 +522,8 @@ def lp_iterate_compressed(
         return i + 1, st
 
     state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state
+    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
+    return state._replace(rounds=rounds)
 
 
 def select_compressed_iterate(choice: str):
@@ -650,7 +650,7 @@ def commit_moves(
 ):
     """Bit-identical fused replacement for lp._commit_moves: same key
     schedule (split + per-purpose draws), same integer auction, one kernel."""
-    labels, label_weights, _ = state
+    labels, label_weights = state.labels, state.label_weights
     kp, ka, kt = jax.random.split(kp, 3)
     n = labels.shape[0]
     coin = (
@@ -856,5 +856,5 @@ def lp_iterate_bucketed(
         return i + 1, st
 
     state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    _, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state
+    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
+    return state._replace(rounds=rounds)

@@ -140,4 +140,5 @@ def _restricted_refine(graph, part, comm, new_k, parent_of_new, inter_bw, ctx):
         jnp.int32(lctx.num_iterations), num_labels=new_k,
         active_prob=lctx.active_prob, allow_tie_moves=lctx.allow_tie_moves,
     )
+    lp_ops.note_rounds(state)
     return state.labels[: pv.n]

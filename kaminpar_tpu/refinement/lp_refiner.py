@@ -84,6 +84,7 @@ class LPRefiner(Refiner):
                     active_prob=self.ctx.active_prob,
                     allow_tie_moves=self.ctx.allow_tie_moves,
                 )
+            lp.note_rounds(state)
             ts.note(state.labels)
             # Zero-transfer pass marker: moved count and cut deliberately
             # stay on device here (this refiner's contract is zero

@@ -384,12 +384,6 @@ class PartitionEngine:
             # regardless of other engines in the process (ISSUE 6).
             if self._solver is None:
                 self._solver = KaMinPar(copy.deepcopy(self.ctx))
-            # Always track compile events (idempotent): the lane-stack
-            # dispatch uses the census to keep warm-hit accounting honest
-            # even on warmup=False engines.
-            from ..utils import compile_stats
-
-            compile_stats.enable_compile_time_tracking()
             if self.resilience.fault_plan:
                 # Arm the context's chaos plan process-wide (seed-keyed, so
                 # the run replays bit-for-bit); env KPTPU_FAULTS outranks
@@ -600,7 +594,6 @@ class PartitionEngine:
                 ))
             return rung_graphs[n]
 
-        compile_stats.enable_compile_time_tracking()
         from ..resilience.errors import ResilienceError, classify
         from ..resilience.faults import maybe_inject
 

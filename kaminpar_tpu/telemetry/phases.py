@@ -50,6 +50,7 @@ AUX_PHASES = (
     "serve_batch_metrics",  # serve/batching.py packed-metrics readback
     "lp_bench_fence",       # bench.py microbench sync fences
     "untracked",            # sync_stats' default phase for unscoped pulls
+    "lp_stats",             # ops/lp.lp_stats_snapshot's LP round-count readback
     # Lane-stacked serve execution (round 11, serve/lanestack.py): the
     # stacked pipeline's scope plus the phase keys its lane-accounted
     # stacked readbacks are counted under (one stacked pull serves the

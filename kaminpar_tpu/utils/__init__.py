@@ -1,4 +1,4 @@
-from . import sync_stats
+from . import compile_stats, sync_stats
 from .assertions import assertion_level, kassert, kassert_heavy, set_assertion_level
 from .logger import Logger, OutputLevel, log_result_line
 from .platform import force_cpu_devices

@@ -25,30 +25,39 @@ lowerings.  The census is **armed explicitly** (:func:`arm_executable_census`)
 and is strictly host-side — lowering abstract shapes and reading analysis
 dicts performs zero device transfers and zero collectives, so an armed run
 is bit-identical to an unarmed one (asserted in tests/test_capacity.py).
-While armed, the jit-cache compile-event listener additionally attributes
-each compile event to the current sync-stats phase
-(:func:`compile_by_phase_snapshot`), so a trace/bench record shows *which
-phase* paid each cold compile.
+The **compile ledger** is always on: one set of ``jax.monitoring``
+listeners, installed when the package is imported, books every program JAX
+traces, lowers, compiles or loads from the persistent cache under the
+program's name and the timer phase open on the dispatching thread
+(:func:`compile_ledger_snapshot`).  It runs only when JAX compiles or loads
+a program, so a warm partition pays nothing for it.
+:func:`compile_time_snapshot` and :func:`compile_by_phase_snapshot` are
+views over it.
 """
 
 from __future__ import annotations
 
+import re
 import threading
 from collections import defaultdict
 
 from ..telemetry import trace as _ttrace
+from . import sync_stats
 
 _lock = threading.Lock()
 _shapes: dict = defaultdict(set)
-_compile_secs = {"backend_compile_s": 0.0, "trace_s": 0.0, "compile_events": 0}
 _listener_installed = False
 _census_armed = False
 # (kind, cell) -> {flops, bytes_accessed, argument_bytes, output_bytes,
 #                  temp_bytes, peak_bytes, generated_code_bytes, count}
 _census: dict = {}
-# phase -> {"events": n, "backend_compile_s": s} (armed-census attribution
-# of the jax.monitoring compile events to the sync-stats phase stack).
-_compile_by_phase: dict = {}
+# The compile ledger: (program, phase) -> a copy of _LEDGER_ROW.
+_LEDGER_ROW = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0, "compiles": 0,
+               "load_s": 0.0, "loads": 0}
+_ledger: dict = {}
+# Per dispatching thread: open trace events, and whether the persistent
+# cache answered the backend-compile event in flight.
+_events_tls = threading.local()
 
 
 def _sig_of(arrays, statics) -> tuple:
@@ -96,58 +105,115 @@ def snapshot() -> dict:
 def reset() -> None:
     with _lock:
         _shapes.clear()
-        _compile_secs.update(
-            {"backend_compile_s": 0.0, "trace_s": 0.0, "compile_events": 0}
-        )
         _census.clear()
-        _compile_by_phase.clear()
+        _ledger.clear()
 
 
-def enable_compile_time_tracking() -> None:
-    """Accumulate actual XLA compile wall-time via jax.monitoring (the
-    '/jax/core/compile/*' duration events).  Idempotent; bench.py turns this
-    on to report per-phase compile cost next to the shape counts."""
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_WRAPPED_NAME = re.compile(r"^\w+\((.*)\)$")
+
+
+def _program_name(fun_name) -> str:
+    """JAX names a trace by the function (``f``) and its lowering and
+    compile by the wrapped program (``jit(f)``); the ledger uses ``f``."""
+    name = str(fun_name)
+    wrapped = _WRAPPED_NAME.match(name)
+    return wrapped.group(1) if wrapped else name
+
+
+def _book(fun_name, field: str, seconds: float, count_field: str | None = None):
+    key = (_program_name(fun_name), sync_stats.active_phase())
+    with _lock:
+        row = _ledger.get(key)
+        if row is None:
+            row = _ledger[key] = dict(_LEDGER_ROW)
+        row[field] += seconds
+        if count_field is not None:
+            row[count_field] += 1
+
+
+def _on_scalar(event, value, **kwargs):
+    # JAX records a scalar when a trace event opens; a trace that opens
+    # inside another (a nested jit traced with its caller) is part of the
+    # outer one's time and is not booked again.
+    if event == _TRACE_EVENT:
+        _events_tls.trace_depth = getattr(_events_tls, "trace_depth", 0) + 1
+
+
+def _on_event(event, **kwargs):
+    # Fired on the compiling thread inside the backend-compile event when the
+    # persistent cache holds the program (jax/_src/compiler.py).
+    if event == _CACHE_HIT_EVENT:
+        _events_tls.cache_hit = True
+
+
+def _on_duration(event, duration, fun_name="", **kwargs):
+    if event == _TRACE_EVENT:
+        depth = getattr(_events_tls, "trace_depth", 1) - 1
+        _events_tls.trace_depth = max(depth, 0)
+        if depth <= 0:
+            _book(fun_name, "trace_s", duration)
+    elif event == _LOWER_EVENT:
+        _book(fun_name, "lower_s", duration)
+    elif event == _BACKEND_EVENT:
+        hit = getattr(_events_tls, "cache_hit", False)
+        _events_tls.cache_hit = False
+        if hit:
+            _book(fun_name, "load_s", duration, "loads")
+        else:
+            _book(fun_name, "compile_s", duration, "compiles")
+
+
+def _install_ledger() -> None:
+    """Install the compile ledger's ``jax.monitoring`` listeners (once; the
+    module's import does)."""
     global _listener_installed
-    if _listener_installed:
-        return
+    with _lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
     import jax.monitoring as monitoring
 
-    def _cb(event, duration, **kwargs):
-        phase = None
-        if _census_armed and event.endswith("backend_compile_duration"):
-            # Attribute the compile to the dispatching thread's sync-stats
-            # phase (the listener fires on the thread that triggered the
-            # compile) — pure host bookkeeping, read before taking the lock.
-            try:
-                from . import sync_stats
+    monitoring.register_scalar_listener(_on_scalar)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
-                phase = sync_stats._phase()
-            except Exception:  # noqa: BLE001 — attribution is best-effort
-                phase = None
-        with _lock:
-            if event.endswith("backend_compile_duration"):
-                _compile_secs["backend_compile_s"] += duration
-                _compile_secs["compile_events"] += 1
-                if phase is not None:
-                    row = _compile_by_phase.setdefault(
-                        phase, {"events": 0, "backend_compile_s": 0.0}
-                    )
-                    row["events"] += 1
-                    row["backend_compile_s"] += duration
-            elif event.endswith("jaxpr_trace_duration"):
-                _compile_secs["trace_s"] += duration
 
-    monitoring.register_event_duration_secs_listener(_cb)
-    _listener_installed = True
+def compile_ledger_snapshot() -> list:
+    """One row per (program, timer phase) that JAX traced, lowered, compiled
+    or loaded: ``trace_s`` (jaxpr tracing, a nested jit inside its caller's),
+    ``lower_s`` (jaxpr to MLIR), ``compile_s``/``compiles`` (backend
+    compiles the persistent cache missed) and ``load_s``/``loads`` (backend
+    compile events it answered).  The phase is the innermost
+    :func:`~kaminpar_tpu.utils.timer.scoped_timer` scope open on the thread
+    that dispatched the program (``"untracked"`` outside any).  Rows come
+    most seconds first."""
+    with _lock:
+        rows = [
+            {"program": program, "phase": phase, **row}
+            for (program, phase), row in _ledger.items()
+        ]
+    return sorted(
+        rows,
+        key=lambda r: -(r["trace_s"] + r["lower_s"] + r["compile_s"] + r["load_s"]),
+    )
 
 
 def compile_time_snapshot() -> dict:
-    with _lock:
-        return {
-            "backend_compile_s": round(_compile_secs["backend_compile_s"], 2),
-            "trace_s": round(_compile_secs["trace_s"], 2),
-            "compile_events": _compile_secs["compile_events"],
-        }
+    """Totals over the ledger: ``backend_compile_s`` and ``compile_events``
+    count backend-compile events, loads from the persistent cache included;
+    ``trace_s`` is jaxpr tracing."""
+    rows = compile_ledger_snapshot()
+    return {
+        "backend_compile_s": round(
+            sum(r["compile_s"] + r["load_s"] for r in rows), 2
+        ),
+        "trace_s": round(sum(r["trace_s"] for r in rows), 2),
+        "compile_events": sum(r["compiles"] + r["loads"] for r in rows),
+    }
 
 
 # -- executable census (round 16, ISSUE 12) ----------------------------------
@@ -160,8 +226,6 @@ def arm_executable_census(on: bool = True) -> None:
     both).  Off by default so tier-1 engine warmups stay cheap."""
     global _census_armed
     _census_armed = bool(on)
-    if on:
-        enable_compile_time_tracking()
 
 
 def executable_census_armed() -> bool:
@@ -288,16 +352,19 @@ def census_peak_temp_bytes(kind: str, cell=()) -> int | None:
 
 
 def compile_by_phase_snapshot() -> dict:
-    """{phase: {events, backend_compile_s}} — which phases paid the cold
-    compiles (populated while the census is armed)."""
-    with _lock:
-        return {
-            ph: {
-                "events": row["events"],
-                "backend_compile_s": round(row["backend_compile_s"], 3),
-            }
-            for ph, row in sorted(_compile_by_phase.items())
-        }
+    """{phase: {events, backend_compile_s}} — which phases paid the
+    backend-compile events (loads from the persistent cache included): the
+    ledger summed over programs."""
+    out: dict = {}
+    for row in compile_ledger_snapshot():
+        acc = out.setdefault(row["phase"], {"events": 0, "backend_compile_s": 0.0})
+        acc["events"] += row["compiles"] + row["loads"]
+        acc["backend_compile_s"] += row["compile_s"] + row["load_s"]
+    return {
+        ph: {"events": acc["events"],
+             "backend_compile_s": round(acc["backend_compile_s"], 3)}
+        for ph, acc in sorted(out.items())
+    }
 
 
 def census_prometheus_families() -> list:
@@ -332,3 +399,6 @@ def census_prometheus_families() -> list:
          "model (telemetry/capacity.py)",
          temps or [({}, None)]),
     ]
+
+
+_install_ledger()

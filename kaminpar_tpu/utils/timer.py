@@ -5,8 +5,10 @@ nested named scopes accumulate wall time into a tree, printed human-readable
 or as machine-readable ``TIME key=value`` lines (kaminpar-shm/kaminpar.cc:50-68).
 On TPU the device work is asynchronous, so scopes that wrap device computation
 should pass ``block=True`` (calls ``jax.block_until_ready`` on a sentinel) or
-time whole jitted calls; additionally each scope emits a
-``jax.profiler.TraceAnnotation`` so timings line up with XLA traces.
+time whole jitted calls.  Each scope also emits a host span
+``jax.profiler.TraceAnnotation("kp/<name>")``, so a profiler trace holds the
+phases on the same clock as the device's programs, and a
+``jax.named_scope`` that names the HLO ops traced inside it.
 
 Thread model (ISSUE 5 satellite): every thread accumulates into its OWN
 subtree — the creating thread owns the primary root, any other thread gets a
@@ -111,31 +113,35 @@ class Timer:
 
     @contextmanager
     def scope(self, name: str):
-        if self._disabled:
-            yield
-            return
-        stack = self._stack()
-        node = stack[-1].child(name)
-        node.starts += 1
-        stack.append(node)
-        rec = _ttrace.active()
-        armed = False
-        if rec is not None:
-            rec.begin(name)
-            armed = rec.arm_profiler(name)
-        start = time.perf_counter()
-        try:
-            import jax
+        """Time ``name`` as a child of this thread's open scope.  The host
+        span ``kp/<name>`` is emitted even while the timer is disabled, so
+        worker threads' phases show on their own lines of a trace."""
+        import jax
 
-            with jax.named_scope(name):
+        with jax.profiler.TraceAnnotation("kp/" + name):
+            if self._disabled:
                 yield
-        finally:
-            node.elapsed += time.perf_counter() - start
-            stack.pop()
+                return
+            stack = self._stack()
+            node = stack[-1].child(name)
+            node.starts += 1
+            stack.append(node)
+            rec = _ttrace.active()
+            armed = False
             if rec is not None:
-                if armed:
-                    rec.disarm_profiler()
-                rec.end(name)
+                rec.begin(name)
+                armed = rec.arm_profiler(name)
+            start = time.perf_counter()
+            try:
+                with jax.named_scope(name):
+                    yield
+            finally:
+                node.elapsed += time.perf_counter() - start
+                stack.pop()
+                if rec is not None:
+                    if armed:
+                        rec.disarm_profiler()
+                    rec.end(name)
 
     # -- reporting ---------------------------------------------------------
 

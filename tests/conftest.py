@@ -39,6 +39,19 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test module.  On the CPU each
+    compiled program holds memory mappings, and a worker that runs many
+    modules otherwise climbs toward the kernel's limit (``vm.max_map_count``,
+    65,530 by default), past which XLA crashes while serializing a program
+    into the compile cache."""
+    yield
+    import jax
+
+    jax.clear_caches()
+
+
 # -- tier-1 wall watch (ISSUE 12 satellite) ----------------------------------
 #
 # Full suite runs append one kind="tier1" ledger entry (suite wall, pass/

@@ -162,7 +162,6 @@ def test_warm_inheritance_zero_compile_delta():
         replicas=2, warm_ladder=(256,), warm_ks=(4,),
     )
     try:
-        compile_stats.enable_compile_time_tracking()
         fleet.replicas[0].start(warmup=True)
         assert fleet.replicas[0].warmup_cell_counts()["local"] >= 1
         before = compile_stats.compile_time_snapshot()["compile_events"]
