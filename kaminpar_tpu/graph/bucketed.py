@@ -17,9 +17,14 @@ Layout invariants (all host-built once per graph, then device-resident):
   inert in ratings (a zero-weight run of the node's own label); in the heavy
   part, pad slots use ``col = anchor``;
 - pad rows: ``node = anchor``; their results are never gathered;
-- ``gather_idx[u]`` = position of node u's row in the concatenation of all
-  bucket rows (buckets in order, then heavy rows), so per-node results are
-  assembled with one gather and no scatter.
+- ``gather_idx`` has one entry per node of the padded node space
+  (``n_pad``, on the shape ladder): ``gather_idx[u]`` = position of real
+  node u's row in the concatenation of all bucket rows (buckets in order,
+  then heavy rows), and -1 on pad nodes.  Per-node results are assembled
+  with one gather and no scatter, and consumers give pad nodes their
+  defaults where ``gather_idx < 0``.  No array of the layout has the real
+  node count as a shape, so a new graph on the same rung and bucket plan
+  reuses every compiled program that takes the layout.
 """
 
 from __future__ import annotations
@@ -57,7 +62,7 @@ class HeavyPart(NamedTuple):
 class BucketedView(NamedTuple):
     buckets: Tuple[Bucket, ...]
     heavy: HeavyPart  # zero-row part when no heavy nodes
-    gather_idx: jax.Array  # (n,) row position of node u in concat(results)
+    gather_idx: jax.Array  # (n_pad,) row of node u in concat(results); -1 on pads
     n: int
 
     @property
@@ -180,7 +185,7 @@ def build_bucketed_view(
     )
 
     buckets = []
-    offsets = np.zeros(n, dtype=np.int64)
+    offsets = np.full(anchor + 1, -1, dtype=np.int64)
     offset = 0
     for w in sorted(int(x) for x in np.unique(width[~heavy_mask])):
         nodes = np.nonzero((~heavy_mask) & (width == w))[0]
@@ -351,7 +356,7 @@ def build_bucketed_view_device(pv, n: int, hist) -> BucketedView:
     idt = pv.col_idx.dtype
     n_dev = jnp.asarray(n)
     m2 = jnp.asarray(merged_to)
-    gather_idx = jnp.zeros(pv.n_pad, dtype=idt)
+    gather_idx = jnp.full(pv.n_pad, -1, dtype=idt)
     buckets = []
     base = 0
     for w, R, R_pad in plan:
@@ -373,5 +378,5 @@ def build_bucketed_view_device(pv, n: int, hist) -> BucketedView:
         z = jnp.zeros(0, dtype=idt)
         heavy = HeavyPart(z, z, z, z)
     return BucketedView(
-        buckets=tuple(buckets), heavy=heavy, gather_idx=gather_idx[:n], n=n
+        buckets=tuple(buckets), heavy=heavy, gather_idx=gather_idx, n=n
     )

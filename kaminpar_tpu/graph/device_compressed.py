@@ -350,12 +350,11 @@ class DeviceCompressedView:
         matrices."""
         from .bucketed import node_width_plan
 
-        n = self.n
         anchor = self.anchor
         bwidth, heavy_mask = node_width_plan(deg)
 
         buckets = []
-        offsets = np.zeros(n, dtype=np.int64)
+        offsets = np.full(self.n_pad, -1, dtype=np.int64)
         offset = 0
         for w in sorted(int(x) for x in np.unique(bwidth[~heavy_mask])):
             nodes = np.nonzero((~heavy_mask) & (bwidth == w))[0]

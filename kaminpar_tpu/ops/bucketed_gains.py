@@ -236,8 +236,6 @@ def bucketed_best_moves(
     returns (target, tconn, own_conn, has_cand) each (n_pad,), with inert
     defaults (no candidate, no move) on pad nodes.
     """
-    n = gather_idx.shape[0]
-    n_pad = labels.shape[0]
     outs = []
     for i, b in enumerate(buckets):
         outs.append(
@@ -268,28 +266,24 @@ def bucketed_best_moves(
             )
         )
 
-    return assemble_moves(outs, gather_idx, labels, n, n_pad)
+    return assemble_moves(outs, gather_idx, labels)
 
 
-def assemble_moves(outs, gather_idx, labels, n: int, n_pad: int):
+def assemble_moves(outs, gather_idx, labels):
     """Gather per-bucket row results into (n_pad,) node arrays with inert
-    defaults on pad nodes.  Shared by the XLA path above and the fused
-    Pallas path (ops/pallas_lp.py), which must assemble identically."""
-    target = jnp.concatenate([o[0] for o in outs])[gather_idx]
-    tconn = jnp.concatenate([o[1] for o in outs])[gather_idx]
-    own_conn = jnp.concatenate([o[2] for o in outs])[gather_idx]
-    has = jnp.concatenate([o[3] for o in outs])[gather_idx]
+    defaults on pad nodes (``gather_idx < 0``): own label, connection 0,
+    own connection 0, no candidate.  Shared by the XLA path above and the
+    fused Pallas path (ops/pallas_lp.py), which must assemble identically."""
+    real = gather_idx >= 0
+    row = jnp.maximum(gather_idx, 0)
 
-    pad = n_pad - n
-    if pad:
-        target = jnp.concatenate([target, labels[n:]])
-        tconn = jnp.concatenate([tconn, jnp.zeros(pad, dtype=tconn.dtype)])
-        own_conn = jnp.concatenate([own_conn, jnp.zeros(pad, dtype=own_conn.dtype)])
-        has = jnp.concatenate([has, jnp.zeros(pad, dtype=bool)])
-    return target, tconn, own_conn, has
+    def gather(j, pad_value):
+        return jnp.where(real, jnp.concatenate([o[j] for o in outs])[row], pad_value)
+
+    return gather(0, labels), gather(1, 0), gather(2, 0), gather(3, False)
 
 
-def bucketed_neighbor_reduce(fn, buckets, heavy: HeavyPart, gather_idx, n_pad: int):
+def bucketed_neighbor_reduce(fn, buckets, heavy: HeavyPart, gather_idx):
     """Generic per-node reduction over neighbors in the bucketed layout.
 
     ``fn(nodes, cols, wgts) -> (R, w) contributions`` is evaluated per bucket
@@ -310,9 +304,5 @@ def bucketed_neighbor_reduce(fn, buckets, heavy: HeavyPart, gather_idx, n_pad: i
                 contrib, hrow, num_segments=hnodes.shape[0], indices_are_sorted=True
             )
         )
-    n = gather_idx.shape[0]
-    flat = jnp.concatenate(outs)[gather_idx]
-    pad = n_pad - n
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros(pad, dtype=flat.dtype)])
-    return flat
+    flat = jnp.concatenate(outs)[jnp.maximum(gather_idx, 0)]
+    return jnp.where(gather_idx >= 0, flat, 0)

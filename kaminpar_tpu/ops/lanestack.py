@@ -20,12 +20,11 @@ construction:
    batches almost always share it) and splits the stack when it diverges;
    ``jax.vmap`` then maps each lane through literally the sequential
    per-lane computation.
-2. **Pad-node masking.** The stacked layout's ``gather_idx`` is full
-   (n_pad,)-length (a per-lane real length would be a shape), so pad nodes
-   gather arbitrary bucket rows; the round replicas below mask the gathered
-   (target, tconn, own_conn) back to the sequential pad defaults
-   (own label, 0, 0) before committing — pad nodes then never move and
-   never perturb the moved-count early exits, exactly as sequentially.
+2. **One layout form.** The stacked layout's ``gather_idx`` is the
+   sequential one: full (n_pad,)-length with -1 on each lane's pad nodes,
+   so the shared assembly (``bucketed_gains.assemble_moves``) gives pads
+   the sequential defaults and the lane programs call the sequential
+   kernels unchanged.
 """
 
 from __future__ import annotations
@@ -47,7 +46,6 @@ from ..graph.bucketed import (
 )
 from ..utils.intmath import next_pow2
 from . import lp
-from .bucketed_gains import bucketed_best_moves
 from .contraction import STATS_LEN, _contract_device, _extract_padded
 
 
@@ -56,60 +54,6 @@ def _unwrap(fn):
     it inside an enclosing jit/vmap avoids donation warnings from the inner
     jit (donation only applies at top-level execution anyway)."""
     return getattr(fn, "__wrapped__", fn)
-
-
-def _mask_pads(labels, n, target, tconn, own_conn):
-    """Force the sequential pad defaults onto pad-node move candidates: the
-    stacked full-length gather gives pads arbitrary row results; sequential
-    ``assemble_moves`` gives them (own label, 0, 0)."""
-    real = jnp.arange(labels.shape[0]) < n
-    return (
-        jnp.where(real, target, labels),
-        jnp.where(real, tconn, 0),
-        jnp.where(real, own_conn, 0),
-    )
-
-
-def _masked_round(state, key, buckets, heavy, gather_idx, node_w, max_w, n,
-                  *, num_labels, active_prob, allow_tie_moves, tie_break):
-    """``lp.lp_round_bucketed`` with the pad mask inserted between the
-    rating gather and the commit — real-slot semantics untouched."""
-    kr, kp = jax.random.split(key)
-    target, tconn, own_conn, _ = bucketed_best_moves(
-        kr, state.labels, buckets, heavy, gather_idx, node_w,
-        state.label_weights, max_w,
-        external_only=False, respect_caps=True, tie_break=tie_break,
-    )
-    target, tconn, own_conn = _mask_pads(state.labels, n, target, tconn, own_conn)
-    return lp._commit_moves(
-        state, kp, target, tconn, own_conn, node_w, max_w, num_labels,
-        active_prob=active_prob, allow_tie_moves=allow_tie_moves,
-    )
-
-
-def _masked_iterate(state, key, buckets, heavy, gather_idx, node_w, max_w,
-                    min_moved, max_iterations, n, *,
-                    num_labels, active_prob, allow_tie_moves, tie_break):
-    """``lp.lp_iterate_bucketed``'s fused sweep loop over the masked round
-    (same carry, same per-round ``fold_in`` keys, same early exit)."""
-    max_iterations = jnp.asarray(max_iterations, dtype=jnp.int32)
-
-    def cond(carry):
-        i, st = carry
-        return (i < max_iterations) & (st.num_moved > min_moved)
-
-    def body(carry):
-        i, st = carry
-        st = _masked_round(
-            st, jax.random.fold_in(key, i), buckets, heavy, gather_idx,
-            node_w, max_w, n, num_labels=num_labels, active_prob=active_prob,
-            allow_tie_moves=allow_tie_moves, tie_break=tie_break,
-        )
-        return i + 1, st
-
-    state = state._replace(num_moved=jnp.int32(jnp.iinfo(jnp.int32).max))
-    rounds, state = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-    return state._replace(rounds=rounds)
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +78,6 @@ def lane_cluster(
     gather_idx,   # (L, n_pad)
     keys_iter,    # (L,) typed keys — the sequential iterate key per lane
     keys_twohop,  # (L,) typed keys (unused rows when two-hop is off)
-    n,            # (L,) real node counts
     max_cw,       # (L,) per-lane max cluster weight
     min_moved,    # (L,) early-exit thresholds
     iters,        # (L,) per-lane sweep budgets (low-degree boost varies them)
@@ -157,14 +100,14 @@ def lane_cluster(
     idt = row_ptr.dtype
     anchor = num_labels - 1
 
-    def one(rp, nw, bks, hv, gi, k_it, k_2h, n_i, mcw, mm, it):
+    def one(rp, nw, bks, hv, gi, k_it, k_2h, mcw, mm, it):
         iota = jnp.arange(num_labels, dtype=idt)
-        labels = jnp.where(iota < n_i, iota, jnp.asarray(anchor, dtype=idt))
+        labels = jnp.where(gi >= 0, iota, jnp.asarray(anchor, dtype=idt))
         state = _unwrap(lp.init_state)(labels, nw, num_labels)
         max_w = mcw.astype(idt)  # scalar limit, as lp_clusterer builds it
-        state = _masked_iterate(
+        state = _unwrap(lp.lp_iterate_bucketed)(
             state, k_it, bks, hv, gi, nw, max_w,
-            mm.astype(jnp.int32), it.astype(jnp.int32), n_i,
+            mm.astype(jnp.int32), it.astype(jnp.int32),
             num_labels=num_labels, active_prob=active_prob,
             allow_tie_moves=False, tie_break=tie_break,
         )
@@ -174,22 +117,14 @@ def lane_cluster(
                 state, rp, nw, max_w, num_labels=num_labels
             )
         if cluster_two_hop:
-            kr, kp = jax.random.split(k_2h)
-            favored, fconn, _, _ = bucketed_best_moves(
-                kr, state.labels, bks, hv, gi, nw, state.label_weights,
-                max_w, external_only=False, respect_caps=False,
-            )
-            favored, fconn, _ = _mask_pads(
-                state.labels, n_i, favored, fconn, fconn
-            )
-            state = _unwrap(lp.two_hop_match)(
-                state, kp, favored, fconn, nw, max_w, num_labels=num_labels
+            state = _unwrap(lp.cluster_two_hop_nodes_bucketed)(
+                state, k_2h, bks, hv, gi, nw, max_w, num_labels=num_labels
             )
         return state.labels, state.num_moved
 
     return jax.vmap(one)(
         row_ptr, node_w, buckets, heavy, gather_idx,
-        keys_iter, keys_twohop, n, max_cw, min_moved, iters,
+        keys_iter, keys_twohop, max_cw, min_moved, iters,
     )
 
 
@@ -295,10 +230,8 @@ def lane_bucketed(row_ptr, col, ew, edge_u, n, merged_to, Rs, Hs, *,
                   plan: tuple, Hr_pad: int, Hs_pad: int):
     """Vmapped device bucketed-view build under the shared ``plan``.
 
-    Returns (buckets, heavy, gather_idx) with (L, ...) leaves.
-    ``gather_idx`` is full (n_pad,)-length — pad nodes keep position 0 and
-    gather arbitrary rows; the masked round replicas above restore the
-    sequential pad defaults, so this never reaches a result."""
+    Returns (buckets, heavy, gather_idx) with (L, ...) leaves, each lane's
+    exactly the sequential :func:`build_bucketed_view_device` layout."""
     from ..utils import compile_stats
 
     compile_stats.record(
@@ -307,7 +240,7 @@ def lane_bucketed(row_ptr, col, ew, edge_u, n, merged_to, Rs, Hs, *,
     idt = col.dtype
 
     def one(rp, c, w_, eu, n_i, m2, r_row, hs_i):
-        gi = jnp.zeros(rp.shape[0] - 1, dtype=idt)
+        gi = jnp.full(rp.shape[0] - 1, -1, dtype=idt)
         bks = []
         base = 0
         for ci, (wd, r_pad) in enumerate(plan):
@@ -342,8 +275,8 @@ def lane_balance_round(keys, labels, buckets, heavy, gather_idx, node_w,
                        max_bw, active, *, k: int):
     """Vmapped overload-balancer round; (labels (L, n_pad), flags (L, 2)).
 
-    No pad mask is needed: the round's mover set requires ``node_w > 0``,
-    so pads can never commit regardless of what they gather.  ``active``
+    Each lane runs the sequential round unchanged (its pads gather no row
+    and keep the sequential defaults).  ``active``
     ((L,) bool) freezes lanes whose sequential round loop already exited —
     their labels pass through unchanged and their (discarded) flags rows
     are computed from the frozen labels."""
@@ -366,10 +299,10 @@ def lane_balance_round(keys, labels, buckets, heavy, gather_idx, node_w,
     static_argnames=("num_labels", "active_prob", "allow_tie_moves"),
 )
 def lane_lp_refine(labels, keys, buckets, heavy, gather_idx, node_w, max_w,
-                   min_moved, iters, n, *,
+                   min_moved, iters, *,
                    num_labels: int, active_prob: float,
                    allow_tie_moves: bool):
-    """Vmapped LP-refiner pass (init_state + fused masked sweep loop);
+    """Vmapped LP-refiner pass (init_state + fused sweep loop);
     returns the refined (L, n_pad) labels.  ``max_w`` is (L, num_labels) —
     per-lane block budgets padded to the shared label bucket."""
     from ..utils import compile_stats
@@ -380,11 +313,11 @@ def lane_lp_refine(labels, keys, buckets, heavy, gather_idx, node_w, max_w,
         statics=(num_labels, active_prob, allow_tie_moves),
     )
 
-    def one(lb, ky, bks, hv, gi, nw, mw, mm, it, n_i):
+    def one(lb, ky, bks, hv, gi, nw, mw, mm, it):
         state = _unwrap(lp.init_state)(lb, nw, num_labels)
-        state = _masked_iterate(
+        state = _unwrap(lp.lp_iterate_bucketed)(
             state, ky, bks, hv, gi, nw, mw,
-            mm.astype(jnp.int32), it.astype(jnp.int32), n_i,
+            mm.astype(jnp.int32), it.astype(jnp.int32),
             num_labels=num_labels, active_prob=active_prob,
             allow_tie_moves=allow_tie_moves, tie_break="uniform",
         )
@@ -392,7 +325,7 @@ def lane_lp_refine(labels, keys, buckets, heavy, gather_idx, node_w, max_w,
 
     return jax.vmap(one)(
         labels, keys, buckets, heavy, gather_idx, node_w, max_w,
-        min_moved, iters, n,
+        min_moved, iters,
     )
 
 

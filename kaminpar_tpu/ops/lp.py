@@ -656,8 +656,6 @@ def compressed_best_moves(
     from ..graph.device_compressed import decode_bucket
     from .bucketed_gains import _bucket_moves, _heavy_moves, assemble_moves
 
-    n = gather_idx.shape[0]
-    n_pad = labels.shape[0]
     outs = []
     for i, cb in enumerate(cbuckets):
         cols, wgts = decode_bucket(stream, cb, jnp.asarray(node_w).dtype)
@@ -678,7 +676,7 @@ def compressed_best_moves(
                 tie_break=tie_break,
             )
         )
-    return assemble_moves(outs, gather_idx, labels, n, n_pad)
+    return assemble_moves(outs, gather_idx, labels)
 
 
 @partial(

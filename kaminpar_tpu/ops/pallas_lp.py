@@ -277,8 +277,6 @@ def pallas_best_moves(
 ):
     """Drop-in, bit-identical equivalent of bucketed_gains.bucketed_best_moves
     with the per-bucket work running in the fused Pallas kernel."""
-    n = gather_idx.shape[0]
-    n_pad = labels.shape[0]
     maxw = jnp.asarray(max_label_weights)
     maxw_scalar = maxw.ndim == 0
     maxw_arr = maxw.reshape(1) if maxw_scalar else maxw
@@ -308,7 +306,7 @@ def pallas_best_moves(
                 tie_break=tie_break,
             )
         )
-    return assemble_moves(outs, gather_idx, labels, n, n_pad)
+    return assemble_moves(outs, gather_idx, labels)
 
 
 # --------------------------------------------------------------------------
@@ -405,8 +403,6 @@ def pallas_compressed_best_moves(
 ):
     """Drop-in, bit-identical equivalent of lp.compressed_best_moves with
     the per-bucket decode + rating fused into one Pallas kernel."""
-    n = gather_idx.shape[0]
-    n_pad = labels.shape[0]
     maxw = jnp.asarray(max_label_weights)
     maxw_scalar = maxw.ndim == 0
     maxw_arr = maxw.reshape(1) if maxw_scalar else maxw
@@ -434,7 +430,7 @@ def pallas_compressed_best_moves(
                 tie_break=tie_break,
             )
         )
-    return assemble_moves(outs, gather_idx, labels, n, n_pad)
+    return assemble_moves(outs, gather_idx, labels)
 
 
 @partial(

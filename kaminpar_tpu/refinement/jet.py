@@ -36,7 +36,6 @@ from .refiner import Refiner
 
 @partial(jax.jit, static_argnames=("k",))
 def _jet_move_round(key, labels, locked, buckets, heavy, gather_idx, node_w, max_bw, temp, *, k: int):
-    n_pad = labels.shape[0]
     block_weights = jax.ops.segment_sum(node_w, labels, num_segments=k)
 
     # --- find -------------------------------------------------------------
@@ -58,7 +57,7 @@ def _jet_move_round(key, labels, locked, buckets, heavy, gather_idx, node_w, max
             eff_v == labels[urow], w, 0
         )
 
-    gain2 = bucketed_neighbor_reduce(contrib_fn, buckets, heavy, gather_idx, n_pad)
+    gain2 = bucketed_neighbor_reduce(contrib_fn, buckets, heavy, gather_idx)
     move = cand & (gain2 > 0)
 
     new_labels = jnp.where(move, target, labels)

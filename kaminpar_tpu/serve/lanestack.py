@@ -576,7 +576,7 @@ class LaneStackRunner:
             keys_2h = keys_iter  # unread
         labels, moved = lops.lane_cluster(
             cur.row_ptr, cur.node_w, buckets, heavy, gather_idx,
-            keys_iter, keys_2h, jnp.asarray(cur.n), jnp.asarray(max_cw),
+            keys_iter, keys_2h, jnp.asarray(max_cw),
             jnp.asarray(min_moved), jnp.asarray(iters),
             num_labels=cur.n_pad, active_prob=active_prob,
             tie_break=cc.lp.tie_breaking.value,
@@ -795,7 +795,6 @@ class LaneStackRunner:
         lab_lp = lops.lane_lp_refine(
             lab, keys, buckets, heavy, gather_idx, level.node_w,
             jnp.asarray(max_w), jnp.asarray(min_moved), jnp.asarray(iters),
-            jnp.asarray(level.n),
             num_labels=k_pad, active_prob=rl.active_prob,
             allow_tie_moves=rl.allow_tie_moves,
         )

@@ -261,3 +261,150 @@ def test_lp_round_identical_on_device_layout():
         )
         outs[name] = np.asarray(state.labels)
     assert np.array_equal(outs["host"], outs["device"])
+
+
+# ---------------------------------------------------------------------------
+# gather_idx on the shape ladder: (n_pad,), -1 on pad nodes.
+# ---------------------------------------------------------------------------
+
+
+def _both_layouts(g):
+    from kaminpar_tpu.graph.bucketed import build_bucketed_view_device
+
+    pv = g.padded()
+    host = build_bucketed_view(
+        np.asarray(g.row_ptr), np.asarray(g.col_idx), np.asarray(g.edge_w),
+        g.n, pv.anchor, min_width=8, max_width=32, min_rows=4,
+    )
+    return pv, {
+        "host": host,
+        "device": build_bucketed_view_device(pv, g.n, g.deg_histogram()),
+    }
+
+
+@pytest.mark.parametrize("builder", ["host", "device"])
+def test_pad_nodes_get_their_defaults(rng, builder):
+    """Pad nodes gather no row: ``assemble_moves`` gives them (own label,
+    0, 0, no candidate) and ``bucketed_neighbor_reduce`` gives them 0,
+    whatever their labels say; real nodes get their rows' results."""
+    from kaminpar_tpu.ops.bucketed_gains import bucketed_neighbor_reduce
+
+    g = _random_graph(rng, n=300, extra_edges=900)
+    pv, views = _both_layouts(g)
+    bv = views[builder]
+    n, n_pad = g.n, pv.n_pad
+    gi = np.asarray(bv.gather_idx)
+    assert gi.shape == (n_pad,)
+    assert np.all(gi[n:] == -1) and np.all(gi[:n] >= 0)
+    assert len(np.unique(gi[:n])) == n
+
+    # Pads carry arbitrary labels, some of them real clusters.
+    labels = jnp.asarray(rng.integers(0, n, n_pad).astype(np.int32))
+    label_weights = jax.ops.segment_sum(pv.node_w, labels, num_segments=n_pad)
+    max_w = jnp.full(n_pad, 1 << 20, dtype=jnp.int32)
+    target, tconn, own_conn, has = (
+        np.asarray(x) for x in bucketed_best_moves(
+            next_key(), labels, bv.buckets, bv.heavy, bv.gather_idx,
+            pv.node_w, label_weights, max_w, external_only=False,
+        )
+    )
+    np.testing.assert_array_equal(target[n:], np.asarray(labels)[n:])
+    assert not tconn[n:].any() and not own_conn[n:].any() and not has[n:].any()
+
+    wdeg = np.asarray(bucketed_neighbor_reduce(
+        lambda nodes, cols, w: w, bv.buckets, bv.heavy, bv.gather_idx
+    ))
+    assert wdeg.shape == (n_pad,)
+    expect = np.add.reduceat(
+        np.asarray(g.edge_w), np.asarray(g.row_ptr)[:-1]
+    ) * (np.diff(np.asarray(g.row_ptr)) > 0)
+    np.testing.assert_array_equal(wdeg[:n], expect)
+    assert not wdeg[n:].any()
+
+
+def test_new_graph_reuses_compiled_layout_programs():
+    """Two graphs on the same ladder rung and bucket plan but of different
+    real node counts share every compiled LP, two-hop and balancer program:
+    the second graph compiles (or loads) none of them."""
+    from kaminpar_tpu.graph.bucketed import MIN_ROWS, _merge_plan
+    from kaminpar_tpu.refinement.balancer import _balance_round
+    from kaminpar_tpu.utils import compile_stats
+
+    programs = ("lp_iterate_bucketed", "cluster_two_hop_nodes_bucketed",
+                "_balance_round")
+
+    def backend_events():
+        got = dict.fromkeys(programs, 0)
+        for row in compile_stats.compile_ledger_snapshot():
+            if row["program"] in got:
+                got[row["program"]] += row["compiles"] + row["loads"]
+        return got
+
+    def run(g):
+        g._layout_mode = "device"
+        pv, bv = g.padded(), g.bucketed()
+        idt = pv.row_ptr.dtype
+        labels = jnp.where(jnp.arange(pv.n_pad) < pv.n,
+                           jnp.arange(pv.n_pad, dtype=idt), pv.anchor)
+        max_w = jnp.asarray(12, dtype=idt)
+        args = (bv.buckets, bv.heavy, bv.gather_idx, pv.node_w)
+        state = lp.lp_iterate_bucketed(
+            lp.init_state(labels, pv.node_w, pv.n_pad), next_key(), *args,
+            max_w, jnp.int32(0), jnp.int32(5), num_labels=pv.n_pad,
+        )
+        state = lp.cluster_two_hop_nodes_bucketed(
+            state, next_key(), *args, max_w, num_labels=pv.n_pad,
+        )
+        part = jnp.where(jnp.arange(pv.n_pad) < pv.n // 3, 0, 1).astype(idt)
+        new_part, _ = _balance_round(
+            next_key(), part, *args, jnp.full(2, pv.n // 2 + 1, dtype=idt), k=2,
+        )
+        jax.block_until_ready((state.labels, new_part))
+        return pv, _merge_plan(g.deg_histogram(), MIN_ROWS)[0]
+
+    graphs = []
+    for n in (1000, 1010):
+        rng = np.random.default_rng(1)
+        graphs.append(generators.from_edge_list(n, rng.integers(0, n, (4000, 2))))
+    pv_a, plan_a = run(graphs[0])
+    before = backend_events()
+    pv_b, plan_b = run(graphs[1])
+    # The premise: one rung, one bucket plan, two node counts.
+    assert (pv_a.n_pad, pv_a.m_pad) == (pv_b.n_pad, pv_b.m_pad)
+    assert [(w, rp) for w, _, rp in plan_a] == [(w, rp) for w, _, rp in plan_b]
+    assert pv_a.n != pv_b.n
+    assert backend_events() == before
+
+
+_GOLDEN_GRAPHS = {
+    "rmat": lambda: generators.rmat_graph(10, 8, seed=3),
+    "grid": lambda: generators.grid2d_graph(24, 24),
+}
+# (cut, first 16 hex digits of the SHA-256 of the int32 partition), recorded
+# when gather_idx still had the real node count as its length.
+_GOLDEN_PARTITIONS = {
+    ("rmat", 2): (438, "efdb406b2e9a4a11"),
+    ("rmat", 4): (1829, "52e8fef7e1e9138b"),
+    ("grid", 2): (28, "9eb6b2cb3bf923c1"),
+    ("grid", 4): (52, "12d522c5130e5c01"),
+}
+
+
+@pytest.mark.parametrize("layout", ["host", "device"])
+@pytest.mark.parametrize("gname,k", sorted(_GOLDEN_PARTITIONS))
+def test_partition_matches_recorded(gname, k, layout):
+    """The facade's partitions and cuts on fixed seeds, with either layout
+    builder: how the layout marks pad nodes must not move a result."""
+    import hashlib
+
+    from kaminpar_tpu.graph import metrics
+    from kaminpar_tpu.kaminpar import KaMinPar
+
+    g = _GOLDEN_GRAPHS[gname]()
+    solver = KaMinPar("default")
+    solver.ctx.seed = 7
+    solver.ctx.parallel.device_layout_build = layout
+    solver.set_graph(g)
+    part = np.asarray(solver.compute_partition(k=k))
+    digest = hashlib.sha256(part.astype(np.int32).tobytes()).hexdigest()[:16]
+    assert (int(metrics.edge_cut(g, part)), digest) == _GOLDEN_PARTITIONS[(gname, k)]

@@ -225,5 +225,5 @@ def test_pallas_round_refused(one_chip, no_cache, tpu_kernels):
             num_labels=N_PAD,
         )
 
-    _refused(round_, state, _key(one_chip), buckets, heavy, s(N), s(N_PAD),
-             s())
+    _refused(round_, state, _key(one_chip), buckets, heavy, s(N_PAD),
+             s(N_PAD), s())
